@@ -19,7 +19,7 @@ Scenario: ``llama3.2-3b`` prefill on the ShareGPT trace (paper §VI-A).
 
 The ``fused_kernel`` record sweeps paper-scale populations across the
 dense / pallas / fused timing backends (megakernel: pass-A gather +
-pass-B recurrence in one VMEM-resident program), asserting interpret-mode
+pass-B recurrence in one SMEM-resident program), asserting interpret-mode
 bitwise parity and labeling every wall number with the backend path that
 actually dispatched on this host (off-TPU: pallas degrades to dense,
 fused runs its fused_host XLA route).
@@ -764,6 +764,9 @@ def run(out_path: str | None = None, population: int | None = None,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=None, help="write JSON here too")
     ap.add_argument("--population", type=int, default=None,

@@ -529,6 +529,9 @@ def run(out_path: str = "BENCH_serving.json", measured: bool = False,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_serving.json",
                     help="output JSON path")

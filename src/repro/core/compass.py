@@ -644,30 +644,21 @@ def _make_population_eval(graphs, tables, hw, use_jax: bool | None,
     """Returns eval(population) -> ((B, P) latency_s, (B, P) energy_j) over
     the group's batches.
 
-    ``timing_backend`` selects the pass-B engine (``oracle`` routes to the
-    pure-numpy evaluator directly — explicit, so no fallback warning).
-    Otherwise the JAX group evaluator is used when available (one jitted
-    call per GA generation for ALL batches of the group), its population
-    axis sharded per ``devices``; ``use_jax=True`` raises on any failure,
-    ``use_jax=None`` warns — loudly, a silent numpy fallback is an
-    order-of-magnitude GA slowdown — and degrades to the numpy oracle."""
+    ``timing_backend`` selects the pass-B engine. ``oracle`` or
+    ``use_jax=False`` is the explicit host path: the pure-numpy evaluator.
+    Otherwise (``use_jax`` None or True) the JAX group evaluator runs —
+    one jitted call per GA generation for ALL batches of the group, its
+    population axis sharded per ``devices`` — and any failure to build it
+    propagates: there is no silent numpy fallback."""
     backend = resolve_timing_backend(timing_backend)
     oracle = isinstance(backend, OracleTimingBackend)
-    if not oracle and (use_jax is None or use_jax):
-        try:
-            from . import jax_evaluator
+    if not oracle and use_jax is not False:
+        from . import jax_evaluator
 
-            ge = jax_evaluator.GroupPopulationEvaluator(graphs, tables, hw,
-                                                        backend=backend,
-                                                        devices=devices)
-            return ge.evaluate_population
-        except Exception as e:
-            if use_jax:
-                raise
-            warnings.warn(
-                "JAX population evaluator unavailable — falling back to the "
-                f"numpy oracle (much slower mapping search): {e!r}",
-                RuntimeWarning, stacklevel=2)
+        ge = jax_evaluator.GroupPopulationEvaluator(graphs, tables, hw,
+                                                    backend=backend,
+                                                    devices=devices)
+        return ge.evaluate_population
 
     def eval_np(population):
         pop = as_stacked(population).to_encodings()

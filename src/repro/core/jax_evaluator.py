@@ -16,7 +16,7 @@ evaluated in one jitted call, structured as:
   part — the makespan recurrence — delegated to a pluggable
   :mod:`repro.core.timing` backend: ``dense`` (batched ``lax.scan``, the
   XLA default), ``pallas`` (``repro.kernels.mapping_eval``, the
-  VMEM-resident TPU kernel over a (batches, population) grid; interpreted
+  SMEM-resident TPU kernel over a (population, batches) grid; interpreted
   on CPU when asked), or ``fused`` (``repro.kernels.mapping_eval_fused``,
   the pass-A + pass-B megakernel: the per-step ``T_proc`` gather happens
   *inside* the kernel via the structural pass's ``sched_idx``, so the
@@ -66,7 +66,6 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from .encoding import MappingEncoding, ScheduledOrderCache, as_stacked
@@ -225,7 +224,7 @@ def _pass_ab(tproc_flat, sched_idx, chip_seq, ppos, n_chips: int,
     ppos (P, T, W) -> (end (B, P, T), chip_free (B, P, C)).
 
     ``fused`` hands the un-gathered rows straight to the megakernel (the
-    (B, P, T) tproc_sched never exists outside VMEM); every other backend
+    (B, P, T) tproc_sched never exists outside SMEM); every other backend
     gathers here and the stages fuse — or not — at XLA's discretion.
     ``fused_host`` is the off-TPU route of the fused backend: one fused
     XLA program, bitwise-identical to ``dense`` by construction (float max
@@ -464,12 +463,12 @@ def _sharded_pass(mesh: "Mesh", grouped: bool, n_chips: int, backend: str,
     out_spec = (PartitionSpec(None, _POP_AXIS) if grouped
                 else PartitionSpec(_POP_AXIS))
     n_out = 5 if full else 2
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(PartitionSpec(_POP_AXIS), PartitionSpec(_POP_AXIS),
                   PartitionSpec()),
         out_specs=(out_spec,) * n_out,
-        check_rep=False))
+        check_vma=False))
     with _SHARDED_PASS_LOCK:
         _SHARDED_PASS_CACHE.setdefault(key, fn)
         return _SHARDED_PASS_CACHE[key]
@@ -827,7 +826,7 @@ class JointStreamEvaluator:
 
     ``group_evals`` maps group key -> ``eval(pop) -> ((B, P) latency_s,
     (B, P) energy_j)`` — a ``GroupPopulationEvaluator.evaluate_population``
-    or the numpy-oracle fallback, so joint mode works on every timing
+    or the numpy-oracle host path, so joint mode works on every timing
     backend; ``groups`` maps group key -> rollout batch indices. Device
     sharding is inherited transitively: when the group evaluators carry a
     ``devices=`` mesh, every group's population shards over it and the

@@ -19,12 +19,12 @@ of the end vector, the oracle's ``max(..., 0)``):
 
 * ``oracle`` — pure-numpy Python loop, the reference semantics;
 * ``dense``  — batched ``lax.scan``, the XLA path (default);
-* ``pallas`` — ``repro.kernels.mapping_eval``, the VMEM-resident TPU
+* ``pallas`` — ``repro.kernels.mapping_eval``, the SMEM-resident TPU
   kernel (one (batch, individual) recurrence per grid step); off-TPU it
   auto-falls back to ``dense`` unless constructed with ``interpret=True``
   (CPU CI runs the exact TPU code path interpreted);
 * ``fused``  — ``repro.kernels.mapping_eval_fused``, the pass-A + pass-B
-  megakernel: the tproc gather and the recurrence run in ONE VMEM-resident
+  megakernel: the tproc gather and the recurrence run in ONE SMEM-resident
   program (tunable grid order, autotuned per shape on TPU). Off-TPU it
   routes to ``mapping_eval_fused_host`` — the same fused contract as one
   jitted XLA program, bitwise-identical to ``dense`` — instead of silently
@@ -220,8 +220,7 @@ class TimingBackend:
 
 class OracleTimingBackend(TimingBackend):
     """Pure-numpy sequential recurrence — the reference semantics every
-    other backend is tested against (and the fallback when jax is
-    unavailable)."""
+    other backend is tested against (the explicit host path)."""
 
     name = "oracle"
 
@@ -309,7 +308,7 @@ class DenseTimingBackend(TimingBackend):
 
 
 class PallasTimingBackend(TimingBackend):
-    """The ``repro.kernels.mapping_eval`` VMEM-resident recurrence.
+    """The ``repro.kernels.mapping_eval`` SMEM-resident recurrence.
     ``interpret=True`` runs the exact TPU code path interpreted on CPU
     (used by CI); ``interpret=None`` auto-detects (compiled on TPU,
     interpreted elsewhere) — :func:`resolve_timing_backend` instead falls
@@ -342,7 +341,7 @@ class PallasTimingBackend(TimingBackend):
 class FusedTimingBackend(PallasTimingBackend):
     """The pass-A + pass-B megakernel
     (``repro.kernels.mapping_eval_fused``): the tproc gather and the
-    timing recurrence run in one VMEM-resident program on the
+    timing recurrence run in one SMEM-resident program on the
     (population, batches) grid, grid order tunable/autotuned.
 
     Off-TPU (and not interpreting) it does NOT degrade to ``dense``: it

@@ -13,20 +13,25 @@ scheduled order, padded with the sentinel T, which indexes the
 permanently-zero slot of the end vector (matching the oracle's
 ``max(..., 0)``).
 
-The recurrence is tiny but strictly sequential in t — on TPU the win is
-evaluating many *independent* (batch x population) members per core with
-all state (the (T+1,) end vector and the (C,) chip-free vector) resident in
-VMEM. Grid = (population, batches) with the batch axis innermost; each
-grid step runs the full T-step recurrence from VMEM scratch via
-``fori_loop`` with dynamic loads/stores. The mapping-dependent index
-tensors (chip sequence, ppos) depend on the individual only, so their
-blocks keep the same index across the inner batch sweep and are fetched
-once per population member.
+The recurrence is tiny but strictly sequential in t, and every access is a
+scalar at a data-dependent index — work for the TPU's scalar unit, not its
+vector unit. So the whole kernel lives in SMEM: one grid step per
+(batch, individual) member, whose index rows, cost row and output rows
+are SMEM blocks, with the ``end`` state in an SMEM scratch vector and the
+per-chiplet free times kept in the output block itself. Mosaic refuses
+dynamic lane-axis loads and stores on VMEM, so a VMEM layout does not
+compile. Every operand is reshaped so that a block's last two dimensions
+equal the array's (``(1, T)`` rows of a ``(P, 1, T)`` array), the layout
+rule of Pallas TPU blocks.
 
-Unlike the original makespan-only kernel, the outputs are the full timing
-matrix — per-op end times in scheduled order plus per-chiplet free times —
-which ``repro.core.timing`` folds into per-request TTFT/TPOT for the
-SLO-aware GA objectives.
+SMEM is small (1 MiB per core on v5e) and every block is double-buffered,
+so the schedule length T is capped: :func:`smem_bytes` prices a call and
+the wrappers raise above :data:`SMEM_BUDGET_BYTES` rather than fall back
+to another backend.
+
+The outputs are the full timing matrix — per-op end times in scheduled
+order plus per-chiplet free times — which ``repro.core.timing`` folds into
+per-request TTFT/TPOT for the SLO-aware GA objectives.
 
 Validated against ``ref.mapping_eval_reference`` (and transitively against
 the numpy evaluation engine, whose timing pass has identical semantics).
@@ -34,12 +39,12 @@ the numpy evaluation engine, whose timing pass has identical semantics).
 ``mapping_eval_fused`` additionally fuses pass A — the gather that
 assembles per-step processing times from the un-gathered per-(batch,
 individual) cost row ``t_proc[l]`` via the schedule's flattened layer
-index ``sched_idx[t]`` — into the same VMEM-resident program, so the
-(B, P, T) ``tproc_sched`` tensor is never materialised between the cost
-pass and the recurrence. The grid order is tunable (``batch_major`` keeps
-one individual's SMEM index tensors resident across the inner batch
-sweep; ``pop_major`` streams individuals fastest) and picked by a small
-timed probe cached per shape when running compiled on TPU.
+index ``sched_idx[t]`` — into the same program, so the (B, P, T)
+``tproc_sched`` tensor is never materialised between the cost pass and
+the recurrence. The grid order is tunable (``batch_major`` keeps one
+individual's index blocks resident across the inner batch sweep;
+``pop_major`` streams individuals fastest) and picked by a small timed
+probe cached per shape when running compiled on TPU.
 """
 from __future__ import annotations
 
@@ -56,31 +61,82 @@ GRID_ORDERS = ("batch_major", "pop_major")
 _GRID_ORDER_ENV = "REPRO_FUSED_GRID_ORDER"
 _AUTOTUNE_CACHE: dict[tuple, str] = {}
 
+# SMEM bytes one call may use: v5e's 1 MiB per core less 16 KiB for what
+# Mosaic adds to :func:`smem_bytes` (6-9 KiB when compiled for a described
+# v5e chip). At W=8 this caps the fused kernel at T=10320.
+SMEM_BUDGET_BYTES = 1024 * 1024 - 16 * 1024
 
-def _mapping_eval_kernel(chip_ref, ppos_ref, tproc_ref, end_ref, free_ref,
-                         end_scr, free_scr, *,
-                         t_len: int, width: int, n_chips: int):
-    end_scr[...] = jnp.zeros_like(end_scr)     # (1, T+1); slot T stays 0
-    free_scr[...] = jnp.zeros_like(free_scr)   # (C, 1)
+
+def smem_bytes(t_len: int, width: int, n_flat: int, n_chips: int,
+               fused: bool) -> int:
+    """SMEM bytes of one kernel call: the double-buffered int32 index rows
+    (chip, ppos and, fused, sched), the f32 cost row and output rows, and
+    the (T+1,) end scratch."""
+    words = t_len * (1 + width + (1 if fused else 0)) + n_flat \
+        + t_len + n_chips
+    return 4 * (2 * words + t_len + 1)
+
+
+def _check_smem(t_len, width, n_flat, n_chips, fused):
+    need = smem_bytes(t_len, width, n_flat, n_chips, fused)
+    if need > SMEM_BUDGET_BYTES:
+        kind = "mapping_eval_fused" if fused else "mapping_eval"
+        raise ValueError(
+            f"{kind}: schedule length T={t_len} (W={width}, L={n_flat}, "
+            f"C={n_chips}) needs {need} bytes of SMEM, over the "
+            f"{SMEM_BUDGET_BYTES}-byte cap of the SMEM layout; use the "
+            "'dense' timing backend for schedules this long")
+
+
+def _recurrence(tproc_at, chip_ref, ppos_ref, end_ref, free_ref, end_scr,
+                *, t_len: int, width: int, n_chips: int):
+    """The pass-B recurrence of one (batch, individual) member, all scalar
+    SMEM accesses. ``tproc_at(t)`` yields step t's processing time."""
+    def zero(i, _):
+        end_scr[i] = jnp.float32(0.0)           # slot T stays 0 (sentinel)
+        return 0
+
+    jax.lax.fori_loop(0, t_len + 1, zero, 0)
+    for c in range(n_chips):
+        free_ref[0, c] = jnp.float32(0.0)
 
     def step(t, _):
         c = chip_ref[0, t]
         pred_end = jnp.float32(0.0)
-        for w in range(width):                 # static unroll; W is small
-            idx = ppos_ref[0, t * width + w]
-            e = pl.load(end_scr, (pl.dslice(0, 1), pl.dslice(idx, 1)))
-            pred_end = jnp.maximum(pred_end, e[0, 0])
-        chip_free = pl.load(free_scr, (pl.dslice(c, 1), slice(None)))
-        start = jnp.maximum(chip_free[0, 0], pred_end)
-        fin = start + tproc_ref[0, 0, t]
-        pl.store(end_scr, (pl.dslice(0, 1), pl.dslice(t, 1)),
-                 fin.reshape(1, 1))
-        pl.store(free_scr, (pl.dslice(c, 1), slice(None)), fin.reshape(1, 1))
+        for w in range(width):                  # static unroll; W is small
+            pred_end = jnp.maximum(pred_end,
+                                   end_scr[ppos_ref[0, t * width + w]])
+        start = jnp.maximum(free_ref[0, c], pred_end)
+        fin = start + tproc_at(t)
+        end_scr[t] = fin
+        end_ref[0, t] = fin
+        free_ref[0, c] = fin
         return 0
 
     jax.lax.fori_loop(0, t_len, step, 0)
-    end_ref[...] = end_scr[0, :t_len].reshape(1, 1, t_len)
-    free_ref[...] = free_scr[:, 0].reshape(1, 1, n_chips)
+
+
+def _mapping_eval_kernel(chip_ref, ppos_ref, tproc_ref, end_ref, free_ref,
+                         end_scr, *, t_len: int, width: int, n_chips: int):
+    _recurrence(lambda t: tproc_ref[0, t], chip_ref, ppos_ref, end_ref,
+                free_ref, end_scr, t_len=t_len, width=width,
+                n_chips=n_chips)
+
+
+def _smem_row(n: int, index_map) -> pl.BlockSpec:
+    """One squeezed (1, n) row of a (..., 1, n) array, held in SMEM."""
+    return pl.BlockSpec((None, 1, n), index_map, memory_space=pltpu.SMEM)
+
+
+def _smem_cell_row(n: int, index_map) -> pl.BlockSpec:
+    """One (1, n) row of a (B, P, 1, n) array, held in SMEM."""
+    return pl.BlockSpec((None, None, 1, n), index_map,
+                        memory_space=pltpu.SMEM)
+
+
+def _out_shapes(n_batch, pop, t_len, n_chips):
+    return [jax.ShapeDtypeStruct((n_batch, pop, 1, t_len), jnp.float32),
+            jax.ShapeDtypeStruct((n_batch, pop, 1, n_chips), jnp.float32)]
 
 
 @functools.partial(jax.jit, static_argnames=("n_chips", "interpret"))
@@ -96,35 +152,27 @@ def mapping_eval(
     free times). The makespan is ``end.max(-1)``."""
     n_batch, pop, t_len = t_proc.shape
     width = ppos.shape[-1]
+    _check_smem(t_len, width, t_len, n_chips, fused=False)
     kernel = functools.partial(_mapping_eval_kernel, t_len=t_len,
                                width=width, n_chips=n_chips)
+    idx = lambda p, b: (p, 0, 0)                       # noqa: E731
+    cell = lambda p, b: (b, p, 0, 0)                   # noqa: E731
     end, free = pl.pallas_call(
         kernel,
         grid=(pop, n_batch),
-        in_specs=[
-            pl.BlockSpec((1, t_len), lambda p, b: (p, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, t_len * width), lambda p, b: (p, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, t_len), lambda p, b: (b, p, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, t_len), lambda p, b: (b, p, 0)),
-            pl.BlockSpec((1, 1, n_chips), lambda p, b: (b, p, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_batch, pop, t_len), jnp.float32),
-            jax.ShapeDtypeStruct((n_batch, pop, n_chips), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, t_len + 1), jnp.float32),
-            pltpu.VMEM((n_chips, 1), jnp.float32),
-        ],
+        in_specs=[_smem_row(t_len, idx),
+                  _smem_row(t_len * width, idx),
+                  _smem_cell_row(t_len, cell)],
+        out_specs=[_smem_cell_row(t_len, cell),
+                   _smem_cell_row(n_chips, cell)],
+        out_shape=_out_shapes(n_batch, pop, t_len, n_chips),
+        scratch_shapes=[pltpu.SMEM((t_len + 1,), jnp.float32)],
         interpret=interpret,
-    )(chip.astype(jnp.int32),
-      ppos.astype(jnp.int32).reshape(pop, t_len * width),
-      t_proc.astype(jnp.float32))
-    return end, free
+        name="mapping_eval",
+    )(chip.astype(jnp.int32).reshape(pop, 1, t_len),
+      ppos.astype(jnp.int32).reshape(pop, 1, t_len * width),
+      t_proc.astype(jnp.float32).reshape(n_batch, pop, 1, t_len))
+    return end[:, :, 0], free[:, :, 0]
 
 
 # --------------------------------------------------------------------------
@@ -133,35 +181,14 @@ def mapping_eval(
 
 
 def _mapping_eval_fused_kernel(sched_ref, chip_ref, ppos_ref, tproc_ref,
-                               end_ref, free_ref, end_scr, free_scr, *,
+                               end_ref, free_ref, end_scr, *,
                                t_len: int, width: int, n_chips: int):
     """One (individual, batch) grid cell: gather each step's processing
     time from the un-gathered cost row (pass A) and run the sequential
-    end/free recurrence (pass B), all from VMEM/SMEM-resident state."""
-    end_scr[...] = jnp.zeros_like(end_scr)     # (1, T+1); slot T stays 0
-    free_scr[...] = jnp.zeros_like(free_scr)   # (C, 1)
-
-    def step(t, _):
-        c = chip_ref[0, t]
-        pred_end = jnp.float32(0.0)
-        for w in range(width):                 # static unroll; W is small
-            idx = ppos_ref[0, t * width + w]
-            e = pl.load(end_scr, (pl.dslice(0, 1), pl.dslice(idx, 1)))
-            pred_end = jnp.maximum(pred_end, e[0, 0])
-        chip_free = pl.load(free_scr, (pl.dslice(c, 1), slice(None)))
-        start = jnp.maximum(chip_free[0, 0], pred_end)
-        li = sched_ref[0, t]                   # pass-A gather, in-kernel
-        tp = pl.load(tproc_ref,
-                     (pl.dslice(0, 1), pl.dslice(0, 1), pl.dslice(li, 1)))
-        fin = start + tp[0, 0, 0]
-        pl.store(end_scr, (pl.dslice(0, 1), pl.dslice(t, 1)),
-                 fin.reshape(1, 1))
-        pl.store(free_scr, (pl.dslice(c, 1), slice(None)), fin.reshape(1, 1))
-        return 0
-
-    jax.lax.fori_loop(0, t_len, step, 0)
-    end_ref[...] = end_scr[0, :t_len].reshape(1, 1, t_len)
-    free_ref[...] = free_scr[:, 0].reshape(1, 1, n_chips)
+    end/free recurrence (pass B), all from SMEM-resident state."""
+    _recurrence(lambda t: tproc_ref[0, sched_ref[0, t]], chip_ref, ppos_ref,
+                end_ref, free_ref, end_scr, t_len=t_len, width=width,
+                n_chips=n_chips)
 
 
 @functools.partial(jax.jit,
@@ -171,52 +198,44 @@ def _mapping_eval_fused_call(t_proc, sched_idx, chip, ppos, n_chips,
     n_batch, pop, n_flat = t_proc.shape
     t_len = chip.shape[-1]
     width = ppos.shape[-1]
+    _check_smem(t_len, width, n_flat, n_chips, fused=True)
     kernel = functools.partial(_mapping_eval_fused_kernel, t_len=t_len,
                                width=width, n_chips=n_chips)
-    # batch_major: the batch axis is innermost, so an individual's SMEM
-    # index tensors (sched/chip/ppos blocks, index constant in b) stay
-    # resident across its whole batch sweep and only the (1, 1, L) cost row
-    # streams — the pipeline double-buffers it one grid step ahead.
+    # batch_major: the batch axis is innermost, so an individual's index
+    # blocks (sched/chip/ppos, index constant in b) stay resident across
+    # its whole batch sweep and only the cost row streams — the pipeline
+    # double-buffers it one grid step ahead.
     # pop_major: the population axis is innermost; every grid step streams
-    # a new individual's index tensors against a resident batch.
+    # a new individual's index blocks against a resident batch.
     if grid_order == "batch_major":
         grid = (pop, n_batch)
-        smem = lambda p, b: (p, 0)                     # noqa: E731
-        vmem = lambda p, b: (b, p, 0)                  # noqa: E731
+        idx = lambda p, b: (p, 0, 0)                   # noqa: E731
+        cell = lambda p, b: (b, p, 0, 0)               # noqa: E731
     elif grid_order == "pop_major":
         grid = (n_batch, pop)
-        smem = lambda b, p: (p, 0)                     # noqa: E731
-        vmem = lambda b, p: (b, p, 0)                  # noqa: E731
+        idx = lambda b, p: (p, 0, 0)                   # noqa: E731
+        cell = lambda b, p: (b, p, 0, 0)               # noqa: E731
     else:
         raise ValueError(f"unknown grid order {grid_order!r}; "
                          f"choose from {GRID_ORDERS}")
     end, free = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, t_len), smem, memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, t_len), smem, memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, t_len * width), smem, memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, n_flat), vmem),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, t_len), vmem),
-            pl.BlockSpec((1, 1, n_chips), vmem),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_batch, pop, t_len), jnp.float32),
-            jax.ShapeDtypeStruct((n_batch, pop, n_chips), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, t_len + 1), jnp.float32),
-            pltpu.VMEM((n_chips, 1), jnp.float32),
-        ],
+        in_specs=[_smem_row(t_len, idx),
+                  _smem_row(t_len, idx),
+                  _smem_row(t_len * width, idx),
+                  _smem_cell_row(n_flat, cell)],
+        out_specs=[_smem_cell_row(t_len, cell),
+                   _smem_cell_row(n_chips, cell)],
+        out_shape=_out_shapes(n_batch, pop, t_len, n_chips),
+        scratch_shapes=[pltpu.SMEM((t_len + 1,), jnp.float32)],
         interpret=interpret,
-    )(sched_idx.astype(jnp.int32),
-      chip.astype(jnp.int32),
-      ppos.astype(jnp.int32).reshape(pop, t_len * width),
-      t_proc.astype(jnp.float32))
-    return end, free
+        name="mapping_eval_fused",
+    )(sched_idx.astype(jnp.int32).reshape(pop, 1, t_len),
+      chip.astype(jnp.int32).reshape(pop, 1, t_len),
+      ppos.astype(jnp.int32).reshape(pop, 1, t_len * width),
+      t_proc.astype(jnp.float32).reshape(n_batch, pop, 1, n_flat))
+    return end[:, :, 0], free[:, :, 0]
 
 
 def default_grid_order() -> str:
@@ -271,7 +290,7 @@ def mapping_eval_fused(
     """Fused pass-A/pass-B timing matrix per (batch, population) member:
     (end [B, P, T], free [B, P, C]). ``t_proc`` is the UN-gathered
     (rows * M)-flat cost row; the kernel gathers step t's processing time
-    as ``t_proc[sched_idx[t]]`` in VMEM, so ``tproc_sched`` never exists
+    as ``t_proc[sched_idx[t]]`` in SMEM, so ``tproc_sched`` never exists
     as a device tensor. ``grid_order=None`` asks the autotune probe (TPU
     compiled runs only; falls back to :func:`default_grid_order`)."""
     if grid_order is None:

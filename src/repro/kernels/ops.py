@@ -1,8 +1,8 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels run in interpret mode — the kernel body
-executes eagerly in Python, validating the exact TPU code path. On a real
-TPU backend they compile to Mosaic. ``use_interpret()`` picks automatically.
+Off-TPU the kernels run in interpret mode — the kernel body executes
+eagerly in Python, validating the exact TPU code path. On a TPU backend
+they compile to Mosaic. ``use_interpret()`` picks from the backend.
 """
 from __future__ import annotations
 

@@ -2,9 +2,9 @@
 
 Per (arch x shape x mesh) cell, three terms:
 
-    t_comp = FLOPs_per_device / peak_flops          (197 TFLOP/s bf16)
-    t_mem  = bytes_per_device / hbm_bw              (819 GB/s)
-    t_coll = collective_bytes_per_device / ici_bw   (50 GB/s/link x 4 links)
+    t_comp = FLOPs_per_device / peak_flops          (v5e: 197 TFLOP/s bf16)
+    t_mem  = bytes_per_device / hbm_bw              (v5e: 819 GB/s)
+    t_coll = collective_bytes_per_device / ici_bw   (v5e: 50 GB/s/link x 4)
 
 Accounting caveat (documented, EXPERIMENTS.md §Dry-run): XLA's
 ``cost_analysis`` counts ``while``-loop bodies ONCE, and our production
@@ -32,10 +32,19 @@ import json
 import os
 from functools import lru_cache
 
-PEAK_FLOPS = 197e12      # bf16 per chip
-HBM_BW = 819e9           # bytes/s per chip
-ICI_LINK_BW = 50e9       # bytes/s per link
-ICI_LINKS = 4            # links per chip participating in collectives
+# Published per-chip peaks, keyed by ``device_kind``. Source: Google Cloud
+# documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s
+# ICI = 4 links x 50 GB/s). The dry-run cells model v5e meshes, so this is
+# the v5e entry, not a default: a chip without an entry has no roofline.
+CHIP_PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9,
+                    "ici_link_bw": 50e9, "ici_links": 4},
+}
+_V5E = CHIP_PEAKS["TPU v5 lite"]
+PEAK_FLOPS = _V5E["flops"]         # bf16 per chip
+HBM_BW = _V5E["hbm_bw"]            # bytes/s per chip
+ICI_LINK_BW = _V5E["ici_link_bw"]  # bytes/s per link
+ICI_LINKS = _V5E["ici_links"]      # links per chip in collectives
 TRAIN_MICROBATCHES = 8   # matches launch/dryrun.py TrainConfig
 
 

@@ -12,6 +12,7 @@ import json
 import jax
 import numpy as np
 
+from ..compile_cache import enable_compile_cache
 from ..configs import all_archs
 from ..models.transformer import init_model, encode
 from ..serving import SCHEDULERS, ServeRequest, ServingEngine, summarize
@@ -60,4 +61,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

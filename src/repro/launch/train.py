@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..compile_cache import enable_compile_cache
 from ..configs import all_archs
 from ..dist.elastic import StragglerMonitor, current_mesh_shape
 from ..dist.sharding import make_param_shardings, token_sharding
@@ -107,4 +108,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

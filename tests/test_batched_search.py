@@ -176,6 +176,9 @@ def test_use_jax_true_raises_instead_of_degrading(monkeypatch):
 
 
 def test_use_jax_auto_warns_on_fallback(monkeypatch):
+    """The numpy fallback under ``use_jax=None`` is gone: an evaluator
+    failure propagates instead of being warned about and hidden, and
+    ``use_jax=False`` stays the explicit (and correct) host path."""
     import repro.core.jax_evaluator as je
 
     def boom(*a, **k):
@@ -183,9 +186,9 @@ def test_use_jax_auto_warns_on_fallback(monkeypatch):
 
     monkeypatch.setattr(je, "GroupPopulationEvaluator", boom)
     graphs, tables = _tiny_group()
-    with pytest.warns(RuntimeWarning, match="numpy oracle"):
-        fn = compass._make_population_eval(graphs, tables, HW, use_jax=None)
-    # the fallback still evaluates correctly
+    with pytest.raises(RuntimeError, match="synthetic jax failure"):
+        compass._make_population_eval(graphs, tables, HW, use_jax=None)
+    fn = compass._make_population_eval(graphs, tables, HW, use_jax=False)
     rng = np.random.default_rng(0)
     pop = [random_encoding(rng, graphs[0].rows, graphs[0].n_cols,
                            HW.n_chiplets)]

@@ -225,3 +225,26 @@ def test_fused_autotune_probe_off_tpu_uses_default(monkeypatch):
     assert me.autotune_grid_order(jnp.asarray(t_proc), jnp.asarray(sched),
                                   jnp.asarray(chip), jnp.asarray(ppos),
                                   2) == "pop_major"
+
+
+@pytest.mark.parametrize("fused", [False, True])
+def test_mapping_eval_smem_cap_raises(fused):
+    """Above the SMEM budget the wrappers raise, naming T and the cap —
+    they never hand the schedule to another backend."""
+    from repro.kernels import mapping_eval as me
+
+    width, chips = 8, 16
+    t_len = 1
+    while me.smem_bytes(t_len, width, t_len, chips, fused) \
+            <= me.SMEM_BUDGET_BYTES:
+        t_len *= 2
+    t_proc = jnp.zeros((1, 1, t_len), jnp.float32)
+    idx = jnp.zeros((1, t_len), jnp.int32)
+    ppos = jnp.full((1, t_len, width), t_len, jnp.int32)
+    with pytest.raises(ValueError,
+                       match=rf"T={t_len}\b.*{me.SMEM_BUDGET_BYTES}-byte cap"):
+        if fused:
+            ops.mapping_eval_fused(t_proc, idx, idx, ppos, chips,
+                                   grid_order="batch_major", interpret=True)
+        else:
+            ops.mapping_eval(t_proc, idx, ppos, chips, interpret=True)
