@@ -1,0 +1,20 @@
+#!/usr/bin/env python3
+"""Run one benchmark cell:
+
+    python3 bench/run.py --workload <name> --seed <n> --seconds <s> --trace 0|1
+
+See ``bench/harness.py``.
+"""
+import time
+
+T_START = time.perf_counter()
+
+import sys  # noqa: E402
+from pathlib import Path  # noqa: E402
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+
+from bench.harness import main  # noqa: E402
+
+if __name__ == "__main__":
+    sys.exit(main(t_start=T_START))
