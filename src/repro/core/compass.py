@@ -29,6 +29,7 @@ from typing import Sequence
 
 import numpy as np
 
+from .. import telemetry
 from ..serving.scheduler import Scheduler, get_scheduler
 from .bo import BOResult, HardwarePoint, bo_search
 from .encoding import (
@@ -340,44 +341,47 @@ def search_mapping(
             "falling back to one_sweep", RuntimeWarning, stacklevel=2)
         cs = replace(cs, mode="one_sweep")
     ga_config = ga_config or GAConfig()
-    # group batches by execution-graph structure
-    groups: dict[tuple, list[int]] = {}
-    graphs, tables = [], []
-    for i, (batch, mb) in enumerate(zip(batches, micro_batches)):
-        g, t = get_graph_and_tables(spec, batch, hw, mb, n_blocks)
-        graphs.append(g)
-        tables.append(t)
-        key = (g.rows, g.n_cols)
-        groups.setdefault(key, []).append(i)
+    telemetry.follow_profiler()
+    with telemetry.span("repro.search"):
+        with telemetry.span("repro.search.setup"):
+            # group batches by execution-graph structure
+            groups: dict[tuple, list[int]] = {}
+            graphs, tables = [], []
+            for i, (batch, mb) in enumerate(zip(batches, micro_batches)):
+                g, t = get_graph_and_tables(spec, batch, hw, mb, n_blocks)
+                graphs.append(g)
+                tables.append(t)
+                key = (g.rows, g.n_cols)
+                groups.setdefault(key, []).append(i)
 
-    # all structurally-identical batches of a group are evaluated in ONE
-    # jitted call per generation (vmap over batches x population)
-    group_evals = {
-        key: _make_population_eval([graphs[i] for i in idxs],
-                                   [tables[i] for i in idxs], hw, use_jax,
-                                   timing_backend, devices=devices)
-        for key, idxs in groups.items()
-    }
+            # all structurally-identical batches of a group are evaluated in ONE
+            # jitted call per generation (vmap over batches x population)
+            group_evals = {
+                key: _make_population_eval([graphs[i] for i in idxs],
+                                           [tables[i] for i in idxs], hw, use_jax,
+                                           timing_backend, devices=devices)
+                for key, idxs in groups.items()
+            }
 
-    stream_fitness = obj.requires_stream
-    base_lat = None
-    if stream_fitness:
-        # best-known per-batch latencies for splicing: seeded from the
-        # pipeline-parallel paradigm, updated after each group's search
-        base_lat = np.zeros(len(batches))
-        for key, idxs in groups.items():
-            rows, m_cols = key
-            seed_lat, _ = group_evals[key]([
-                pipeline_parallel(rows, m_cols, hw.n_chiplets)])
-            base_lat[idxs] = np.asarray(seed_lat)[:, 0]
+        stream_fitness = obj.requires_stream
+        base_lat = None
+        if stream_fitness:
+            # best-known per-batch latencies for splicing: seeded from the
+            # pipeline-parallel paradigm, updated after each group's search
+            base_lat = np.zeros(len(batches))
+            for key, idxs in groups.items():
+                rows, m_cols = key
+                seed_lat, _ = group_evals[key]([
+                    pipeline_parallel(rows, m_cols, hw.n_chiplets)])
+                base_lat[idxs] = np.asarray(seed_lat)[:, 0]
 
-    ctx = _SearchContext(
-        graphs=graphs, tables=tables, groups=groups,
-        group_evals=group_evals, hw=hw, obj=obj, ga_config=ga_config,
-        stream_rollout=stream_rollout, base_lat=base_lat, cs=cs)
-    if cs.mode == "joint":
-        return _search_joint(ctx)
-    return _search_rounds(ctx)
+        ctx = _SearchContext(
+            graphs=graphs, tables=tables, groups=groups,
+            group_evals=group_evals, hw=hw, obj=obj, ga_config=ga_config,
+            stream_rollout=stream_rollout, base_lat=base_lat, cs=cs)
+        if cs.mode == "joint":
+            return _search_joint(ctx)
+        return _search_rounds(ctx)
 
 
 @dataclass
@@ -426,8 +430,9 @@ class _SearchContext:
     def oracle_latencies(self, key, enc) -> "list[EvalResult]":
         """Reference-price one group's encoding per batch (the numbers
         ``base_lat`` and the final output are built from)."""
-        return [evaluate(self.graphs[i], enc, self.hw, self.tables[i])
-                for i in self.groups[key]]
+        with telemetry.span("repro.search.oracle"):
+            return [evaluate(self.graphs[i], enc, self.hw, self.tables[i])
+                    for i in self.groups[key]]
 
     def rollout_score(self, lat_vec: np.ndarray) -> float:
         """Scenario objective of a full per-batch latency vector."""

@@ -23,6 +23,8 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from .. import telemetry
+
 
 @dataclass
 class MappingEncoding:
@@ -177,7 +179,10 @@ class ScheduledOrderCache:
     segmentation bits: across GA generations most individuals keep their
     segmentation (elites, children without a seg mutation), so their (T, 2)
     order tensors are reused and only the changed rows are re-derived (in
-    one vectorised ``scheduled_orders`` call)."""
+    one vectorised ``scheduled_orders`` call). Hits and misses also count
+    process-wide under ``eval.order_hits`` / ``eval.order_misses`` in
+    ``repro.telemetry``, and each call is a ``repro.eval.orders`` span
+    carrying its own ``hits`` and ``misses``."""
 
     def __init__(self, rows: int, m_cols: int, capacity: int = 8192):
         self.rows, self.m_cols = rows, m_cols
@@ -187,27 +192,33 @@ class ScheduledOrderCache:
         self.misses = 0
 
     def orders(self, segmentations: np.ndarray) -> np.ndarray:
-        seg = np.ascontiguousarray(np.asarray(segmentations, dtype=np.uint8))
-        p = seg.shape[0]
-        out = np.empty((p, self.rows * self.m_cols, 2), dtype=np.int32)
-        missing: list[int] = []
-        keys = [seg[i].tobytes() for i in range(p)]
-        for i, kb in enumerate(keys):
-            hit = self._cache.get(kb)
-            if hit is None:
-                missing.append(i)
-            else:
-                out[i] = hit
-                self.hits += 1
-        if missing:
-            self.misses += len(missing)
-            fresh = scheduled_orders(seg[missing], self.rows, self.m_cols)
-            if len(self._cache) + len(missing) > self.capacity:
-                self._cache.clear()
-            for j, i in enumerate(missing):
-                out[i] = fresh[j]
-                self._cache[keys[i]] = fresh[j]
-        return out
+        with telemetry.span("repro.eval.orders") as sp:
+            seg = np.ascontiguousarray(np.asarray(segmentations, dtype=np.uint8))
+            p = seg.shape[0]
+            out = np.empty((p, self.rows * self.m_cols, 2), dtype=np.int32)
+            missing: list[int] = []
+            keys = [seg[i].tobytes() for i in range(p)]
+            for i, kb in enumerate(keys):
+                hit = self._cache.get(kb)
+                if hit is None:
+                    missing.append(i)
+                else:
+                    out[i] = hit
+                    self.hits += 1
+            if missing:
+                self.misses += len(missing)
+                fresh = scheduled_orders(seg[missing], self.rows, self.m_cols)
+                if len(self._cache) + len(missing) > self.capacity:
+                    self._cache.clear()
+                for j, i in enumerate(missing):
+                    out[i] = fresh[j]
+                    self._cache[keys[i]] = fresh[j]
+            hits, misses = p - len(missing), len(missing)
+            telemetry.bump("eval.order_hits", hits)
+            telemetry.bump("eval.order_misses", misses)
+            if sp:
+                sp.set(hits=hits, misses=misses)
+            return out
 
 
 # --------------------------------------------------------------------------

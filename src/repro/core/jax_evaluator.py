@@ -68,6 +68,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from .. import telemetry
 from .encoding import MappingEncoding, ScheduledOrderCache, as_stacked
 from .evaluator import CostTables
 from .hardware import (
@@ -228,22 +229,25 @@ def _pass_ab(tproc_flat, sched_idx, chip_seq, ppos, n_chips: int,
     gathers here and the stages fuse — or not — at XLA's discretion.
     ``fused_host`` is the off-TPU route of the fused backend: one fused
     XLA program, bitwise-identical to ``dense`` by construction (float max
-    is exact, one add per step in identical order)."""
-    if backend == "fused":
-        from ..kernels.mapping_eval import mapping_eval_fused
+    is exact, one add per step in identical order). Its device operations
+    sit under the ``timing_pass`` named scope (after ``structural_pass``
+    and ``cost_pass``), whichever backend runs."""
+    with jax.named_scope("timing_pass"):
+        if backend == "fused":
+            from ..kernels.mapping_eval import mapping_eval_fused
 
-        return mapping_eval_fused(tproc_flat, sched_idx, chip_seq, ppos,
-                                  n_chips, grid_order=grid_order,
-                                  interpret=interpret)
-    tproc = _gather_sched(tproc_flat, sched_idx)
-    if backend == "pallas":
-        from ..kernels.mapping_eval import mapping_eval
+            return mapping_eval_fused(tproc_flat, sched_idx, chip_seq, ppos,
+                                      n_chips, grid_order=grid_order,
+                                      interpret=interpret)
+        tproc = _gather_sched(tproc_flat, sched_idx)
+        if backend == "pallas":
+            from ..kernels.mapping_eval import mapping_eval
 
-        return mapping_eval(tproc, chip_seq, ppos, n_chips,
-                            interpret=interpret)
-    # dense and fused_host: the proven batched-scan formulation
-    per_p = jax.vmap(lambda tp, c, pp: dense_pass_b(tp, c, pp, n_chips))
-    return jax.vmap(lambda tp: per_p(tp, chip_seq, ppos))(tproc)
+            return mapping_eval(tproc, chip_seq, ppos, n_chips,
+                                interpret=interpret)
+        # dense and fused_host: the proven batched-scan formulation
+        per_p = jax.vmap(lambda tp, c, pp: dense_pass_b(tp, c, pp, n_chips))
+        return jax.vmap(lambda tp: per_p(tp, chip_seq, ppos))(tproc)
 
 
 def _population_pass_impl(
@@ -273,16 +277,18 @@ def _population_pass_impl(
     full: bool = False,
     grid_order: str = "batch_major",
 ):
-    struct = jax.vmap(
-        lambda o, lc: _structural_pass(o, lc, n_succ, hops, pred_cols,
-                                       pred_valid, n_chips)
-    )(order_rc, l2c)
-    tproc, energy = jax.vmap(
-        lambda s, lc: _cost_pass(s, lc, pred_cols, dram_hops, flow_of_chip,
-                                 ws_resident, out_bytes, comp_s, comp_e,
-                                 weight_b, psum_b, output_b, rr, stream_b,
-                                 extra_w, dram_bw, nop_bw)
-    )(struct, l2c)                                # (P, rows, M), (P,)
+    with jax.named_scope("structural_pass"):
+        struct = jax.vmap(
+            lambda o, lc: _structural_pass(o, lc, n_succ, hops, pred_cols,
+                                           pred_valid, n_chips)
+        )(order_rc, l2c)
+    with jax.named_scope("cost_pass"):
+        tproc, energy = jax.vmap(
+            lambda s, lc: _cost_pass(s, lc, pred_cols, dram_hops, flow_of_chip,
+                                     ws_resident, out_bytes, comp_s, comp_e,
+                                     weight_b, psum_b, output_b, rr, stream_b,
+                                     extra_w, dram_bw, nop_bw)
+        )(struct, l2c)                                # (P, rows, M), (P,)
     tproc_flat = tproc.reshape(tproc.shape[0], -1)[None]  # (1, P, L)
     end, free = _pass_ab(tproc_flat, struct["sched_idx"],
                          struct["chip_seq"], struct["ppos"],
@@ -317,10 +323,11 @@ def _grouped_population_pass_impl(
 ):
     # structural pass once per individual — shared across the group's
     # batches (it depends on the mapping only, not the byte tables)
-    struct = jax.vmap(
-        lambda o, lc: _structural_pass(o, lc, n_succ, hops, pred_cols,
-                                       pred_valid, n_chips)
-    )(order_rc, l2c)
+    with jax.named_scope("structural_pass"):
+        struct = jax.vmap(
+            lambda o, lc: _structural_pass(o, lc, n_succ, hops, pred_cols,
+                                           pred_valid, n_chips)
+        )(order_rc, l2c)
 
     def per_batch(ws_r, ob, cs, ce, wb, pb, o_b, rr_b, sb, ew):
         return jax.vmap(
@@ -329,9 +336,10 @@ def _grouped_population_pass_impl(
                                      pb, o_b, rr_b, sb, ew, dram_bw, nop_bw)
         )(struct, l2c)
 
-    tproc, energy = jax.vmap(per_batch)(
-        ws_resident, out_bytes, comp_s, comp_e, weight_b, psum_b, output_b,
-        rr, stream_b, extra_w)                    # (B, P, rows, M), (B, P)
+    with jax.named_scope("cost_pass"):
+        tproc, energy = jax.vmap(per_batch)(
+            ws_resident, out_bytes, comp_s, comp_e, weight_b, psum_b, output_b,
+            rr, stream_b, extra_w)                    # (B, P, rows, M), (B, P)
     tproc_flat = tproc.reshape(tproc.shape[:2] + (-1,))   # (B, P, L)
     end, free = _pass_ab(tproc_flat, struct["sched_idx"],
                          struct["chip_seq"], struct["ppos"],
@@ -669,31 +677,35 @@ class PopulationEvaluator:
             # clamp/wrap them into silently-wrong prices
             assert_population_legal(pop, self._n_chips, graph=self.graph)
         orders = self._order_cache.orders(pop.segmentation)
-        if self._mesh is None:
-            return _population_pass(
-                jnp.asarray(orders), jnp.asarray(pop.layer_to_chip),
-                n_chips=self._n_chips, backend=self._backend,
-                interpret=self._interpret, full=full,
-                grid_order=self._grid_order, **self._static)
-        orders, l2c, p0 = pad_population(
-            np.asarray(orders), np.asarray(pop.layer_to_chip),
-            self._mesh.size)
-        fn = _sharded_pass(self._mesh, False, self._n_chips, self._backend,
-                           self._interpret, full, self._grid_order)
-        out = fn(orders, l2c, self._static)
-        if p0 != orders.shape[0]:
-            out = tuple(o[:p0] for o in out)
-        return out
+        with telemetry.span("repro.eval.dispatch"):
+            if self._mesh is None:
+                return _population_pass(
+                    jnp.asarray(orders), jnp.asarray(pop.layer_to_chip),
+                    n_chips=self._n_chips, backend=self._backend,
+                    interpret=self._interpret, full=full,
+                    grid_order=self._grid_order, **self._static)
+            orders, l2c, p0 = pad_population(
+                np.asarray(orders), np.asarray(pop.layer_to_chip),
+                self._mesh.size)
+            fn = _sharded_pass(self._mesh, False, self._n_chips, self._backend,
+                               self._interpret, full, self._grid_order)
+            out = fn(orders, l2c, self._static)
+            if p0 != orders.shape[0]:
+                out = tuple(o[:p0] for o in out)
+            return out
 
     def evaluate_population(
         self, population: "Sequence[MappingEncoding]"
     ) -> tuple[np.ndarray, np.ndarray]:
         """Returns (latency_s, energy_j) arrays over the population.
         Accepts a list of encodings or a ``StackedPopulation``."""
-        lat, en_pj = self._run(population)
-        scale = self.graph.scale
-        return (np.asarray(lat, np.float64) * scale,
-                np.asarray(en_pj, np.float64) * 1e-12 * scale)
+        with telemetry.span("repro.eval"):
+            lat, en_pj = self._run(population)
+            with telemetry.span("repro.eval.fetch"):
+                lat = np.asarray(lat, np.float64)
+                en_pj = np.asarray(en_pj, np.float64)
+            scale = self.graph.scale
+            return lat * scale, en_pj * 1e-12 * scale
 
     def timing_matrix(self, population) -> TimingMatrix:
         """Full per-op timing matrix (P, T)/(P, C), block scale applied."""
@@ -765,31 +777,35 @@ class GroupPopulationEvaluator:
             assert_population_legal(pop, self._n_chips,
                                     graph=self.graphs[0])
         orders = self._order_cache.orders(pop.segmentation)
-        if self._mesh is None:
-            return _grouped_population_pass(
-                jnp.asarray(orders), jnp.asarray(pop.layer_to_chip),
-                n_chips=self._n_chips, backend=self._backend,
-                interpret=self._interpret, full=full,
-                grid_order=self._grid_order, **self._static)
-        orders, l2c, p0 = pad_population(
-            np.asarray(orders), np.asarray(pop.layer_to_chip),
-            self._mesh.size)
-        fn = _sharded_pass(self._mesh, True, self._n_chips, self._backend,
-                           self._interpret, full, self._grid_order)
-        out = fn(orders, l2c, self._static)
-        if p0 != orders.shape[0]:
-            out = tuple(o[:, :p0] for o in out)
-        return out
+        with telemetry.span("repro.eval.dispatch"):
+            if self._mesh is None:
+                return _grouped_population_pass(
+                    jnp.asarray(orders), jnp.asarray(pop.layer_to_chip),
+                    n_chips=self._n_chips, backend=self._backend,
+                    interpret=self._interpret, full=full,
+                    grid_order=self._grid_order, **self._static)
+            orders, l2c, p0 = pad_population(
+                np.asarray(orders), np.asarray(pop.layer_to_chip),
+                self._mesh.size)
+            fn = _sharded_pass(self._mesh, True, self._n_chips, self._backend,
+                               self._interpret, full, self._grid_order)
+            out = fn(orders, l2c, self._static)
+            if p0 != orders.shape[0]:
+                out = tuple(o[:, :p0] for o in out)
+            return out
 
     def evaluate_population(
         self, population
     ) -> tuple[np.ndarray, np.ndarray]:
         """population (list of encodings or StackedPopulation) ->
         ((B, P) latency_s, (B, P) energy_j)."""
-        lat, en_pj = self._run(population)
-        scale = self._scales[:, None]
-        return (np.asarray(lat, np.float64) * scale,
-                np.asarray(en_pj, np.float64) * 1e-12 * scale)
+        with telemetry.span("repro.eval"):
+            lat, en_pj = self._run(population)
+            with telemetry.span("repro.eval.fetch"):
+                lat = np.asarray(lat, np.float64)
+                en_pj = np.asarray(en_pj, np.float64)
+            scale = self._scales[:, None]
+            return lat * scale, en_pj * 1e-12 * scale
 
     def timing_matrix(self, population) -> TimingMatrix:
         """Full (B, P, T) timing matrix, block scale applied. The GA hot

@@ -23,6 +23,7 @@ use it whenever "why is the search slow / fat" comes up.
 """
 from __future__ import annotations
 
+from .. import telemetry
 from . import timing
 
 
@@ -35,15 +36,17 @@ def cache_stats() -> dict:
     (device-buffer cache hits/misses/entries), ``device_resident_bytes``
     (per-device bytes of the cached stacked buffers) plus its total.
     Degrades to the host-side stats alone when JAX is unavailable.
-    Also carries a ``serving`` section: the process-wide serving engine /
-    paged-cache counters (iterations, block residency, OOM/blocked
-    admissions, transfer-pool hit rates) and a ``timing_backend``
-    section: per-backend pass-B dispatch counts plus off-TPU fallback
-    reroutes (``pallas->dense``, ``fused->host``)."""
+    Also carries a ``timing_backend`` section (per-backend pass-B
+    dispatch counts plus off-TPU fallback reroutes, ``pallas->dense``,
+    ``fused->host``) and a ``telemetry`` section: the process-wide
+    counters of ``repro.telemetry`` (serving iterations, block residency,
+    OOM/blocked admissions, the order cache's hits and misses, compiles
+    while tracing) under ``counters``, and count, total and self time of
+    every span recorded while tracing was on under ``spans``."""
     out: dict = {"cost_tables": timing.cost_cache_stats(),
-                 "timing_backend": timing.timing_backend_stats()}
-    from ..serving import stats as serving_stats
-    out["serving"] = serving_stats.snapshot()
+                 "timing_backend": timing.timing_backend_stats(),
+                 "telemetry": {"counters": telemetry.snapshot(),
+                               "spans": telemetry.span_totals()}}
     try:
         from . import jax_evaluator
     except Exception:                           # pragma: no cover - no jax

@@ -18,8 +18,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import telemetry
 from ..models.transformer import ModelConfig, decode_step, extend, init_cache
-from . import stats as serving_stats
 from .scheduler import (
     Scheduler,
     ServeRequest,
@@ -129,7 +129,7 @@ class ServingEngine:
         running: list[ServeRequest] = []
         finished: list[ServeRequest] = []
         stats: list[IterationStats] = []
-        serving_stats.bump("engine_runs")
+        telemetry.bump("engine_runs")
         it = 0
         while (pending or waiting or running) and it < max_iters:
             admit_arrivals(pending, waiting, running, self.free, it)
@@ -178,18 +178,18 @@ class ServingEngine:
                 time.perf_counter() - t0,
                 queue_depth=queue_depth,
                 slots_used=self.max_batch - len(self.free)))
-            serving_stats.bump("iterations")
-            serving_stats.bump("prefill_tokens", n_prefill_tok)
-            serving_stats.bump("decode_tokens", len(plan.decode))
-            serving_stats.high_water("peak_slots_used",
-                                     self.max_batch - len(self.free))
-            serving_stats.high_water("peak_queue_depth", queue_depth)
+            telemetry.bump("iterations")
+            telemetry.bump("prefill_tokens", n_prefill_tok)
+            telemetry.bump("decode_tokens", len(plan.decode))
+            telemetry.high_water("peak_slots_used",
+                                 self.max_batch - len(self.free))
+            telemetry.high_water("peak_queue_depth", queue_depth)
             it += 1
 
         unfinished = pending + waiting + running
         if unfinished:
-            serving_stats.bump("truncated_runs")
-            serving_stats.bump("unfinished_requests", len(unfinished))
+            telemetry.bump("truncated_runs")
+            telemetry.bump("unfinished_requests", len(unfinished))
             warnings.warn(
                 f"engine run truncated at max_iters={max_iters} with "
                 f"{len(unfinished)} request(s) still in flight — they are "

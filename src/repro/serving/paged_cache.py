@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.paged import NULL_BLOCK, init_paged_pools, is_slot_layer
-from . import stats
+from .. import telemetry
 
 __all__ = ["BlockAllocator", "PagedKVCache", "TransferBufferPool"]
 
@@ -74,13 +74,13 @@ class BlockAllocator:
         need = self.blocks_for(n_tokens)
         if need > self.blocks_free:
             self.oom_events += 1
-            stats.bump("oom_events")
+            telemetry.bump("oom_events")
             return False
         blocks = [self._free.pop() for _ in range(need)]
         self._tables[rid] = blocks
         self.peak_used = max(self.peak_used, self.blocks_used)
-        stats.bump("blocks_reserved", need)
-        stats.high_water("peak_blocks_used", self.blocks_used)
+        telemetry.bump("blocks_reserved", need)
+        telemetry.high_water("peak_blocks_used", self.blocks_used)
         return True
 
     def table(self, rid: int) -> list[int]:
@@ -91,7 +91,7 @@ class BlockAllocator:
         reservation reuses the hottest blocks). Returns the count."""
         blocks = self._tables.pop(rid)
         self._free.extend(reversed(blocks))
-        stats.bump("blocks_freed", len(blocks))
+        telemetry.bump("blocks_freed", len(blocks))
         return len(blocks)
 
     def owners(self) -> dict[int, list[int]]:
@@ -184,10 +184,8 @@ class TransferBufferPool:
         pool = self._pools.setdefault(key, [])
         if pool:
             self.hits += 1
-            stats.bump("transfer_pool_hits")
             return pool.pop()
         self.misses += 1
-        stats.bump("transfer_pool_misses")
         return np.empty(shape, dtype)
 
     def release(self, buf: np.ndarray) -> None:

@@ -37,6 +37,7 @@ from functools import partial
 
 import numpy as np
 
+from .. import telemetry
 from ..core.streams import RequestStream, RequestTimings, StreamRollout
 from ..core.workload import DECODE, PREFILL, Request
 from .clock import IterationClock, WallClock
@@ -49,7 +50,6 @@ from .scheduler import (
     get_scheduler,
     retire_finished,
 )
-from . import stats
 
 __all__ = ["ServiceConfig", "AsyncLLMService", "ServiceResult",
            "golden_parity_stream", "service_requests",
@@ -82,6 +82,9 @@ class ServiceResult:
     rollout: StreamRollout              # the schedule actually executed
     admissions: list[tuple[int, int, int]]   # (rid, slot, iter), in order
     iteration_seconds: np.ndarray       # measured wall seconds per iter
+    # rid -> {"arrival_s", "admit_s", "first_s", "done_s"}: seconds from
+    # the serve's start at which the request arrived, took a slot, got
+    # its first token and finished (admit_s - arrival_s: the queue wait)
     wall_events: dict[int, dict[str, float]]
     truncated: bool
     counters: dict = field(default_factory=dict)
@@ -173,7 +176,6 @@ class AsyncLLMService:
 
             prefill_fn.__name__ = f"prefill_bs1_c{chunk_bucket}"
             self._prefill_fns[chunk_bucket] = jax.jit(prefill_fn)
-            stats.bump("prefill_entrypoints")
         return self._prefill_fns[chunk_bucket]
 
     def _decode_entry(self, batch_bucket: int):
@@ -190,7 +192,6 @@ class AsyncLLMService:
 
             decode_fn.__name__ = f"decode_bs{batch_bucket}"
             self._decode_fns[batch_bucket] = jax.jit(decode_fn)
-            stats.bump("decode_entrypoints")
         return self._decode_fns[batch_bucket]
 
     # -- admission ----------------------------------------------------------
@@ -211,7 +212,7 @@ class AsyncLLMService:
             if head.slot is None and \
                     not self.kv.allocator.can_reserve(self._demand(head)):
                 self._iter_blocked += 1
-                stats.bump("blocked_admissions")
+                telemetry.bump("blocked_admissions")
                 return 0
         return free
 
@@ -223,11 +224,12 @@ class AsyncLLMService:
             return False
         if not self.kv.allocator.reserve(req.rid, self._demand(req)):
             self._iter_blocked += 1
-            stats.bump("blocked_admissions")
+            telemetry.bump("blocked_admissions")
             return False
         req.slot = self.free.pop()
         self.kv.bind(req.slot, req.rid)
         self._admissions.append((req.rid, req.slot, it))
+        self._stamp(req.rid, "admit_s")
         if prefault:
             self._prefault(req)
         return True
@@ -250,7 +252,7 @@ class AsyncLLMService:
                 req, len(req.prompt) - req.prefilled)
         assert req.prefilled == target
         self._warm_seed[req.rid] = tok
-        stats.bump("warm_prefaults")
+        telemetry.bump("warm_prefaults")
 
     # -- producer / engine handshake ---------------------------------------
 
@@ -260,7 +262,7 @@ class AsyncLLMService:
             await self.clock.sleep_until(r.arrived_iter)
             await self._queue.put(r)
             self._stamp(r.rid, "arrival_s")
-            stats.high_water("peak_queue_depth", self._queue.qsize())
+            telemetry.high_water("peak_queue_depth", self._queue.qsize())
         self._next_arrival = None
         self._producer_done = True
 
@@ -293,59 +295,70 @@ class AsyncLLMService:
 
     def _run_prefill_chunk(self, req: ServeRequest, chunk_len: int) -> int:
         import jax.numpy as jnp
+        span = telemetry.span
         slot = req.slot
-        chunk = req.prompt[req.prefilled: req.prefilled + chunk_len]
-        n = len(chunk)
-        c = _bucket(n)
-        buf = self.xfer.acquire((c,), np.int32)
-        buf[:] = 0
-        buf[:n] = chunk
-        fn = self._prefill_entry(c)
-        tok, self.kv.pools = fn(
-            self.params, jnp.asarray(buf), self.kv.pools,
-            jnp.asarray(self.kv.tables_np[slot]),
-            jnp.asarray(self.kv.lens_np[slot], jnp.int32),
-            jnp.asarray(slot, jnp.int32),
-            jnp.asarray(n, jnp.int32))
-        self.xfer.release(buf)
-        req.prefilled += n
-        self.kv.lens_np[slot] += n
-        stats.bump("prefill_tokens", n)
-        return int(tok)
+        with span("repro.serve.prefill", rid=req.rid):
+            with span("repro.serve.prefill.stage"):
+                chunk = req.prompt[req.prefilled: req.prefilled + chunk_len]
+                n = len(chunk)
+                c = _bucket(n)
+                buf = self.xfer.acquire((c,), np.int32)
+                buf[:] = 0
+                buf[:n] = chunk
+                fn = self._prefill_entry(c)
+                args = (jnp.asarray(buf),
+                        jnp.asarray(self.kv.tables_np[slot]),
+                        jnp.asarray(self.kv.lens_np[slot], jnp.int32),
+                        jnp.asarray(slot, jnp.int32),
+                        jnp.asarray(n, jnp.int32))
+            with span("repro.serve.prefill.dispatch"):
+                tok, self.kv.pools = fn(self.params, args[0], self.kv.pools,
+                                        *args[1:])
+            self.xfer.release(buf)
+            req.prefilled += n
+            self.kv.lens_np[slot] += n
+            telemetry.bump("prefill_tokens", n)
+            with span("repro.serve.prefill.fetch"):
+                return int(tok)
 
     def _run_decode(self, decode: list) -> None:
         import jax.numpy as jnp
+        span = telemetry.span
         n = len(decode)
-        b = _bucket(n)
-        t = self.kv.blocks_per_seq
-        tok_buf = self.xfer.acquire((b,), np.int32)
-        tbl_buf = self.xfer.acquire((b, t), np.int32)
-        len_buf = self.xfer.acquire((b,), np.int32)
-        slot_buf = self.xfer.acquire((b,), np.int32)
-        tok_buf[:] = 0
-        tbl_buf[:] = 0                      # null block: pad-lane sink
-        len_buf[:] = 0
-        slot_buf[:] = self.kv.scratch_slot  # pad-lane recurrent-state sink
-        for j, r in enumerate(decode):
-            # warm requests have no generated token yet at their first
-            # decode: seed with the prefault's final prefill token
-            tok_buf[j] = r.generated[-1] if r.generated \
-                else self._warm_seed[r.rid]
-            tbl_buf[j] = self.kv.tables_np[r.slot]
-            len_buf[j] = self.kv.lens_np[r.slot]
-            slot_buf[j] = r.slot
-        fn = self._decode_entry(b)
-        toks, self.kv.pools = fn(
-            self.params, jnp.asarray(tok_buf), self.kv.pools,
-            jnp.asarray(tbl_buf), jnp.asarray(len_buf),
-            jnp.asarray(slot_buf))
-        toks = np.asarray(toks)
-        for j, r in enumerate(decode):
-            r.generated.append(int(toks[j]))
-            self.kv.lens_np[r.slot] += 1
-        for buf in (tok_buf, tbl_buf, len_buf, slot_buf):
-            self.xfer.release(buf)
-        stats.bump("decode_tokens", n)
+        with span("repro.serve.decode"):
+            with span("repro.serve.decode.stage"):
+                b = _bucket(n)
+                t = self.kv.blocks_per_seq
+                tok_buf = self.xfer.acquire((b,), np.int32)
+                tbl_buf = self.xfer.acquire((b, t), np.int32)
+                len_buf = self.xfer.acquire((b,), np.int32)
+                slot_buf = self.xfer.acquire((b,), np.int32)
+                tok_buf[:] = 0
+                tbl_buf[:] = 0                      # null block: pad-lane sink
+                len_buf[:] = 0
+                slot_buf[:] = self.kv.scratch_slot  # pad-lane recurrent-state sink
+                for j, r in enumerate(decode):
+                    # warm requests have no generated token yet at their first
+                    # decode: seed with the prefault's final prefill token
+                    tok_buf[j] = r.generated[-1] if r.generated \
+                        else self._warm_seed[r.rid]
+                    tbl_buf[j] = self.kv.tables_np[r.slot]
+                    len_buf[j] = self.kv.lens_np[r.slot]
+                    slot_buf[j] = r.slot
+                fn = self._decode_entry(b)
+                args = (jnp.asarray(tok_buf), jnp.asarray(tbl_buf),
+                        jnp.asarray(len_buf), jnp.asarray(slot_buf))
+            with span("repro.serve.decode.dispatch"):
+                toks, self.kv.pools = fn(self.params, args[0], self.kv.pools,
+                                         *args[1:])
+            with span("repro.serve.decode.fetch"):
+                toks = np.asarray(toks)
+            for j, r in enumerate(decode):
+                r.generated.append(int(toks[j]))
+                self.kv.lens_np[r.slot] += 1
+            for buf in (tok_buf, tbl_buf, len_buf, slot_buf):
+                self.xfer.release(buf)
+            telemetry.bump("decode_tokens", n)
 
     # -- the service loop ---------------------------------------------------
 
@@ -390,7 +403,7 @@ class AsyncLLMService:
         self._wall_events: dict[int, dict[str, float]] = {}
         self._wall_t0 = time.perf_counter()
         self._iter_blocked = 0
-        stats.bump("services_started")
+        telemetry.bump("services_started")
         self._producer_task = asyncio.ensure_future(self._producer(reqs))
         try:
             return await self._engine_loop(reqs, scheduler, stream_name)
@@ -413,7 +426,9 @@ class AsyncLLMService:
         kept_its: list[int] = []
         batches: list[list[Request]] = []
         it = 0
+        span = telemetry.span
         while it < self.config.max_iters:
+            telemetry.follow_profiler()
             await self._deliver(it, pending)
             if not (pending or waiting or running):
                 if (self._producer_done or self._producer_task.done()) \
@@ -428,85 +443,92 @@ class AsyncLLMService:
                     continue
                 pending.append(await self._queue.get())
                 continue
-            # warm arrivals admit through the shared loop with the
-            # service's richer admission (block reservation + context
-            # prefault) substituted for the planner's bare try_admit;
-            # the blocked counter resets FIRST so a block-starved warm
-            # head shows up in this iteration's stats
-            self._iter_blocked = 0
-            admit_arrivals(pending, waiting, running, self.free, it,
-                           admit=lambda r, _f: self._admit(r, it,
-                                                           prefault=True))
-            free_eff = self._schedulable_slots(waiting)
-            plan = scheduler.plan(waiting, running, free_eff)
-            prefill = [(q, n) for q, n in plan.prefill
-                       if self._admit(q, it)]
-            plan = IterationPlan(prefill=prefill, decode=list(plan.decode))
-            if not plan.prefill and not plan.decode:
-                if not waiting and not running and pending:
-                    nxt = pending[0].arrived_iter
-                    if nxt > it:
-                        it = int(nxt)      # fast-forward the idle gap
-                        continue
-                it += 1
-                if not self.clock.deterministic:
-                    await asyncio.sleep(0)
-                continue
+            with span("repro.serve.iter"):
+                with span("repro.serve.iter.plan"):
+                    # warm arrivals admit through the shared loop with the
+                    # service's richer admission (block reservation + context
+                    # prefault) substituted for the planner's bare try_admit;
+                    # the blocked counter resets FIRST so a block-starved warm
+                    # head shows up in this iteration's stats
+                    self._iter_blocked = 0
+                    admit_arrivals(pending, waiting, running, self.free, it,
+                                   admit=lambda r, _f: self._admit(
+                                       r, it, prefault=True))
+                    free_eff = self._schedulable_slots(waiting)
+                    plan = scheduler.plan(waiting, running, free_eff)
+                    prefill = [(q, n) for q, n in plan.prefill
+                               if self._admit(q, it)]
+                    plan = IterationPlan(prefill=prefill,
+                                         decode=list(plan.decode))
+                if not plan.prefill and not plan.decode:
+                    if not waiting and not running and pending:
+                        nxt = pending[0].arrived_iter
+                        if nxt > it:
+                            it = int(nxt)      # fast-forward the idle gap
+                            continue
+                    it += 1
+                    if not self.clock.deterministic:
+                        await asyncio.sleep(0)
+                    continue
 
-            # record the batch with pre-iteration state (plan_rollout's
-            # yield-time convention), then execute it
-            queue_depth = len(waiting) + self._queue.qsize()
-            batch = [Request(PREFILL, n, q.prefilled + n)
-                     for q, n in plan.prefill]
-            batch += [Request(DECODE, 1, r.prefilled + len(r.generated))
-                      for r in plan.decode]
-            # warm first-token convention (the planner's): a warm
-            # request's first scheduled decode is its first token
-            newly_first_warm = [
-                r.rid for r in plan.decode
-                if r.rid in self._warm_rids
-                and r.rid not in self._warm_first_b]
-            for rid in newly_first_warm:
-                self._warm_first_b[rid] = len(batches)
-            t0 = time.perf_counter()
-            n_prefill_tok = 0
-            for req, chunk_len in plan.prefill:
-                tok = self._run_prefill_chunk(req, chunk_len)
-                n_prefill_tok += chunk_len
-                if req.prefill_done:
-                    req.generated.append(tok)
-                    complete_prefill(req, it, waiting, running)
-                    self._stamp(req.rid, "first_s")
-            if plan.decode:
-                self._run_decode(plan.decode)
+                # record the batch with pre-iteration state (plan_rollout's
+                # yield-time convention), then execute it
+                queue_depth = len(waiting) + self._queue.qsize()
+                batch = [Request(PREFILL, n, q.prefilled + n)
+                         for q, n in plan.prefill]
+                batch += [Request(DECODE, 1, r.prefilled + len(r.generated))
+                          for r in plan.decode]
+                # warm first-token convention (the planner's): a warm
+                # request's first scheduled decode is its first token
+                newly_first_warm = [
+                    r.rid for r in plan.decode
+                    if r.rid in self._warm_rids
+                    and r.rid not in self._warm_first_b]
                 for rid in newly_first_warm:
-                    self._stamp(rid, "first_s")
-            owned = {r.rid: r.slot for r in running}
-            n_done = len(finished)
-            retire_finished(running, finished, self.free, it)
-            for r in finished[n_done:]:
-                self.kv.release(owned[r.rid], r.rid)
-                self._stamp(r.rid, "done_s")
-            it_stats.append(IterationStats(
-                it, n_prefill_tok, len(plan.decode),
-                time.perf_counter() - t0,
-                queue_depth=queue_depth,
-                slots_used=self.config.max_batch - len(self.free),
-                blocks_used=self.kv.allocator.blocks_used,
-                blocked_admissions=self._iter_blocked))
-            kept_its.append(it)
-            batches.append(batch)
-            stats.bump("iterations")
-            stats.high_water("peak_slots_used",
-                             self.config.max_batch - len(self.free))
+                    self._warm_first_b[rid] = len(batches)
+                t0 = time.perf_counter()
+                n_prefill_tok = 0
+                if plan.prefill:
+                    with span("repro.serve.iter.prefill"):
+                        for req, chunk_len in plan.prefill:
+                            tok = self._run_prefill_chunk(req, chunk_len)
+                            n_prefill_tok += chunk_len
+                            if req.prefill_done:
+                                req.generated.append(tok)
+                                complete_prefill(req, it, waiting, running)
+                                self._stamp(req.rid, "first_s")
+                if plan.decode:
+                    with span("repro.serve.iter.decode"):
+                        self._run_decode(plan.decode)
+                        for rid in newly_first_warm:
+                            self._stamp(rid, "first_s")
+                with span("repro.serve.iter.retire"):
+                    owned = {r.rid: r.slot for r in running}
+                    n_done = len(finished)
+                    retire_finished(running, finished, self.free, it)
+                    for r in finished[n_done:]:
+                        self.kv.release(owned[r.rid], r.rid)
+                        self._stamp(r.rid, "done_s")
+                    it_stats.append(IterationStats(
+                        it, n_prefill_tok, len(plan.decode),
+                        time.perf_counter() - t0,
+                        queue_depth=queue_depth,
+                        slots_used=self.config.max_batch - len(self.free),
+                        blocks_used=self.kv.allocator.blocks_used,
+                        blocked_admissions=self._iter_blocked))
+                    kept_its.append(it)
+                    batches.append(batch)
+                    telemetry.bump("iterations")
+                    telemetry.high_water("peak_slots_used",
+                                         self.config.max_batch - len(self.free))
             it += 1
 
         fin_rids = {r.rid for r in finished}
         unfinished = [r for r in reqs if r.rid not in fin_rids]
         truncated = bool(unfinished)
         if truncated:
-            stats.bump("truncated_runs")
-            stats.bump("unfinished_requests", len(unfinished))
+            telemetry.bump("truncated_runs")
+            telemetry.bump("unfinished_requests", len(unfinished))
             warnings.warn(
                 f"service run truncated at max_iters={self.config.max_iters}"
                 f" with {len(unfinished)} request(s) unfinished — measured "

@@ -210,7 +210,7 @@ def test_occupancy_stats_and_counters(served):
     assert s["requests"] == STREAM.n_requests
     assert s["mean_slots_used"] > 0
     from repro.core.observability import cache_stats
-    serving = cache_stats()["serving"]
+    serving = cache_stats()["telemetry"]["counters"]
     assert serving["services_started"] >= 1
     assert serving["prefill_tokens"] > 0
 
