@@ -13,6 +13,7 @@ from .flash_attention import flash_attention as _flash_attention
 from .mapping_eval import mapping_eval as _mapping_eval
 from .mapping_eval import mapping_eval_fused as _mapping_eval_fused
 from .mapping_eval import mapping_eval_fused_host
+from .paged_attention import paged_decode_attention as _paged_decode_attention
 from .ssd_scan import ssd_scan as _ssd_scan
 
 
@@ -31,6 +32,15 @@ def decode_attention(q, k_cache, v_cache, lengths, scale=None, block_s=512,
                      interpret=None):
     return _decode_attention(
         q, k_cache, v_cache, lengths, scale=scale, block_s=block_s,
+        interpret=use_interpret() if interpret is None else interpret)
+
+
+def paged_decode_attention(q, k_pool, v_pool, tables, lengths,
+                           interpret=None):
+    """Decode attention read in place from ``[num_blocks, block_len,
+    Hkv * D]`` pools through ``tables`` [B, T], over ``lengths`` [B]."""
+    return _paged_decode_attention(
+        q, k_pool, v_pool, tables, lengths,
         interpret=use_interpret() if interpret is None else interpret)
 
 

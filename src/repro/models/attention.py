@@ -291,11 +291,7 @@ def attention_decode(p, x, cfg, rope, cache, impl="xla"):
                        p["w_uv"]["w"].reshape(r, hq, hd))
         return dense(p["wo"], y.reshape(b, -1))[:, None, :], cache
 
-    q = dense(p["wq"], x1).reshape(b, hq, hd)
-    k = dense(p["wk"], x1).reshape(b, hkv, hd)
-    v = dense(p["wv"], x1).reshape(b, hkv, hd)
-    q = apply_rope(q, pos[:, None], cos, sin)
-    k = apply_rope(k, pos[:, None], cos, sin)
+    q, k, v = decode_qkv(p, x1, cfg, rope, pos)
     if cache["k"].dtype == jnp.int8:
         qk, sk = _quantize_kv(k[:, None])
         qv2, sv = _quantize_kv(v[:, None])
@@ -317,6 +313,20 @@ def attention_decode(p, x, cfg, rope, cache, impl="xla"):
         o = _xla_decode(q, kc, vc, lengths)
     o = o.astype(x.dtype)
     return dense(p["wo"], o.reshape(b, -1))[:, None, :], cache
+
+
+def decode_qkv(p, x1, cfg, rope, pos):
+    """MHA/GQA projections of one new token per sequence, rotated at
+    ``pos`` [B]. x1: [B, d] -> q [B, Hq, D], k and v [B, Hkv, D]."""
+    b = x1.shape[0]
+    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    cos, sin = rope
+    q = dense(p["wq"], x1).reshape(b, hq, hd)
+    k = dense(p["wk"], x1).reshape(b, hkv, hd)
+    v = dense(p["wv"], x1).reshape(b, hkv, hd)
+    q = apply_rope(q, pos[:, None], cos, sin)
+    k = apply_rope(k, pos[:, None], cos, sin)
+    return q, k, v
 
 
 def _xla_decode(q, k_cache, v_cache, lengths):

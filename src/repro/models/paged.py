@@ -19,18 +19,29 @@ Index conventions (shared with ``repro.serving.paged_cache``):
   slot-indexed: arrays carry ``max_batch + 1`` rows and the extra last row
   is the **scratch slot** used by batch-padding lanes.
 
-The compute paths below *gather* a request batch's blocks into the dense
-layout, run the unmodified ``decode_step`` / ``extend`` model functions,
-and scatter the touched positions back through the block table — so paged
-execution is bit-identical in its unmasked reads to the dense engine, which
-is exactly the parity the serving tests pin down.
+* float MHA/GQA attention pools (``k``/``v``) are lane-dense
+  ``[num_blocks, block_len, Hkv * D]``: one block row holds every KV head of
+  a position, the layout the paged decode kernel DMAs whole. Every other
+  pool (MLA latent ``kv``, int8 ``k``/``v`` with ``*_scale``) keeps the
+  dense cache's trailing dims. Only this module and the kernel read the
+  layout.
+
+The decode step reads float attention pools in place: it writes the new
+position through the block table, then ``kernels/paged_attention.py``
+attends over each request's live blocks alone. Every other layer kind, and
+the prefill path, *gathers* a request batch's blocks into the dense layout,
+runs the unmodified ``decode_step`` / ``extend`` model functions, and
+scatters the touched positions back through the block table — bit-identical
+in its unmasked reads to the dense engine.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from .attention import init_attn_cache
+from ..kernels import ops
+from .attention import attention_decode, decode_qkv, init_attn_cache
+from .layers import dense
 from .mamba2 import init_mamba_cache
 from .transformer import ModelConfig, decode_step, extend
 
@@ -42,11 +53,19 @@ def is_slot_layer(layer: dict) -> bool:
     return "state" in layer
 
 
+def reads_in_place(layer: dict) -> bool:
+    """Float MHA/GQA attention pools: the decode kernel reads them through
+    the block table. MLA latents, int8 pools and slot state are gathered."""
+    return set(layer) == {"k", "v"} and \
+        jnp.issubdtype(layer["k"].dtype, jnp.floating)
+
+
 def init_paged_pools(cfg: ModelConfig, max_batch: int, num_blocks: int,
                      block_len: int, dtype=jnp.float32):
     """Per-layer pools: attention layers get ``[num_blocks, block_len, ...]``
-    KV pools (reusing the dense cache constructor with the pool shape);
-    recurrent layers get slot state with one extra scratch row."""
+    KV pools (reusing the dense cache constructor with the pool shape, float
+    ``k``/``v`` with their heads flattened into lanes); recurrent layers get
+    slot state with one extra scratch row."""
     pools = []
     for i in range(cfg.n_layers):
         if cfg.mixer_kind(i) == "attn":
@@ -54,53 +73,79 @@ def init_paged_pools(cfg: ModelConfig, max_batch: int, num_blocks: int,
         else:
             c = init_mamba_cache(cfg, max_batch + 1)
         c.pop("len")            # lengths live host-side, per slot
+        if reads_in_place(c):
+            c = {k: v.reshape(num_blocks, block_len, -1) for k, v in c.items()}
         pools.append(c)
     return pools
 
 
-def gather_paged_cache(pools, tables, lens, slots):
+def _gather_layer(layer, tables, lens, slots, cfg: ModelConfig):
+    n, t = tables.shape
+    if is_slot_layer(layer):
+        d = {k: v[slots] for k, v in layer.items()}
+    else:
+        heads = (cfg.n_kv_heads, cfg.head_dim) if reads_in_place(layer) \
+            else None
+        d = {}
+        for k, pool in layer.items():
+            g = pool[tables]                           # [N, T, bl, ...]
+            d[k] = g.reshape((n, t * pool.shape[1]) + (heads or pool.shape[2:]))
+    d["len"] = lens
+    return d
+
+
+def gather_paged_cache(pools, tables, lens, slots, cfg: ModelConfig):
     """Assemble the dense per-request cache view a model function expects.
 
     ``tables``: [N, T] int32 block ids; ``lens``: [N] live context lengths;
-    ``slots``: [N] slot ids for the recurrent state rows. Returns a cache
-    list in the dense engine layout ([N, T*block_len, ...] per attention
-    layer) — positions past ``lens`` hold whatever the referenced blocks
-    hold (the null block included) and rely on length masking downstream.
+    ``slots``: [N] slot ids for the recurrent state rows; ``cfg`` splits the
+    lanes of float ``k``/``v`` pools back into ``(n_kv_heads, head_dim)``.
+    Returns a cache list in the dense engine layout ([N, T*block_len, ...]
+    per attention layer) — positions past ``lens`` hold whatever the
+    referenced blocks hold (the null block included) and rely on length
+    masking downstream.
     """
-    n, t = tables.shape
-    cache = []
-    for layer in pools:
-        if is_slot_layer(layer):
-            d = {k: v[slots] for k, v in layer.items()}
-        else:
-            d = {}
-            for k, pool in layer.items():
-                g = pool[tables]                       # [N, T, bl, ...]
-                d[k] = g.reshape((n, t * pool.shape[1]) + pool.shape[2:])
-        d["len"] = lens
-        cache.append(d)
-    return cache
+    return [_gather_layer(layer, tables, lens, slots, cfg) for layer in pools]
 
 
 def paged_decode(params, cfg: ModelConfig, tokens, pools, tables, lens,
                  slots, block_len: int, impl: str = "xla"):
     """One decode step for a batch of paged requests.
 
-    Gathers each request's blocks into the dense layout, runs the stock
-    ``decode_step``, then scatters exactly one written KV position per
-    request back through its block table (position ``lens[j]`` lands in
-    block ``tables[j, lens[j] // block_len]``). Padding lanes must use the
-    null block table and the scratch slot so their writes are sunk.
+    Writes each request's new KV position through its block table (position
+    ``lens[j]`` lands in block ``tables[j, lens[j] // block_len]``). Float
+    attention layers then attend in place over the pool, ``lens + 1``
+    positions through the table (``ops.paged_decode_attention``); every
+    other layer runs the stock decode over the gathered dense view, and its
+    one written position is scattered back. Padding lanes must use the null
+    block table and the scratch slot so their writes are sunk.
     Returns ``(argmax tokens [N], new pools)``.
     """
-    cache = gather_paged_cache(pools, tables, lens, slots)
-    logits, new_cache = decode_step(params, cfg, tokens, cache, impl=impl)
     n = tokens.shape[0]
     bidx = jnp.take_along_axis(tables, (lens // block_len)[:, None],
                                axis=1)[:, 0]           # [N] target blocks
     off = lens % block_len
+    cache = [layer if reads_in_place(layer)
+             else _gather_layer(layer, tables, lens, slots, cfg)
+             for layer in pools]
+
+    def attend(i, p, x, rope, c):
+        if not reads_in_place(pools[i]):
+            return attention_decode(p, x, cfg, rope, c, impl=impl)
+        q, k, v = decode_qkv(p, x[:, 0], cfg, rope, lens)
+        new = {name: c[name].at[bidx, off].set(
+                   val.reshape(n, -1).astype(c[name].dtype))
+               for name, val in (("k", k), ("v", v))}
+        o = ops.paged_decode_attention(q, new["k"], new["v"], tables, lens + 1)
+        return dense(p["wo"], o.astype(x.dtype).reshape(n, -1))[:, None], new
+
+    logits, new_cache = decode_step(params, cfg, tokens, cache, impl=impl,
+                                    attend=attend)
     new_pools = []
     for layer, new in zip(pools, new_cache):
+        if reads_in_place(layer):
+            new_pools.append(new)
+            continue
         if is_slot_layer(layer):
             new_pools.append(
                 {k: layer[k].at[slots].set(new[k]) for k in layer})
@@ -133,7 +178,7 @@ def paged_extend(params, cfg: ModelConfig, tokens, pools, table, off, slot,
     w = (c + block_len - 1) // block_len + 1           # window, static
     lens1 = jnp.reshape(off, (1,))
     slots1 = jnp.reshape(slot, (1,))
-    cache = gather_paged_cache(pools, table[None], lens1, slots1)
+    cache = gather_paged_cache(pools, table[None], lens1, slots1, cfg)
     logits, new_cache = extend(params, cfg, tokens[None], cache, impl=impl,
                                length=length)
     w0 = off // block_len
@@ -152,7 +197,7 @@ def paged_extend(params, cfg: ModelConfig, tokens, pools, table, off, slot,
             row = jnp.pad(row, pad)
             win = jax.lax.dynamic_slice_in_dim(row, w0 * block_len,
                                                w * block_len, axis=0)
-            win = win.reshape((w, block_len) + row.shape[1:])
+            win = win.reshape((w, block_len) + pool.shape[2:])
             d[k] = pool.at[safe].set(win.astype(pool.dtype))
         new_pools.append(d)
     return jnp.argmax(logits, -1)[0], new_pools

@@ -386,21 +386,25 @@ def _mask_cache(old, new, active):
 
 
 def decode_step(params, cfg: ModelConfig, token, cache, enc_out=None,
-                impl="xla", active=None):
+                impl="xla", active=None, attend=None):
     """One decode step. token: [B] int32 -> (logits [B, vocab], cache).
     ``active``: optional [B] bool — inactive slots' caches are left
-    untouched (continuous batching with partially-filled slots)."""
+    untouched (continuous batching with partially-filled slots).
+    ``attend``: optional ``(i, p, h, rope, cache_i) -> (h, cache_i)`` that
+    stands in for ``attention_decode`` at attention layer ``i``."""
     x = embed(params["embed"], token)[:, None, :]
     b = x.shape[0]
     rope = rope_freqs(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
     new_cache = []
     for i, blk in enumerate(params["blocks"]):
         h = _norm(cfg, blk["norm1"], x)
-        if cfg.mixer_kind(i) == "attn":
+        if cfg.mixer_kind(i) != "attn":
+            h, c = mamba_decode(blk["mamba"], h, cfg, cache[i], impl=impl)
+        elif attend is not None:
+            h, c = attend(i, blk["attn"], h, rope, cache[i])
+        else:
             h, c = attention_decode(blk["attn"], h, cfg, rope, cache[i],
                                     impl=impl)
-        else:
-            h, c = mamba_decode(blk["mamba"], h, cfg, cache[i], impl=impl)
         new_cache.append(_mask_cache(cache[i], c, active))
         x = x + h
         if enc_out is not None and "cross" in blk:

@@ -326,7 +326,7 @@ class AsyncLLMService:
         span = telemetry.span
         n = len(decode)
         with span("repro.serve.decode"):
-            with span("repro.serve.decode.stage"):
+            with span("repro.serve.decode.stage") as stage:
                 b = _bucket(n)
                 t = self.kv.blocks_per_seq
                 tok_buf = self.xfer.acquire((b,), np.int32)
@@ -345,6 +345,13 @@ class AsyncLLMService:
                     tbl_buf[j] = self.kv.tables_np[r.slot]
                     len_buf[j] = self.kv.lens_np[r.slot]
                     slot_buf[j] = r.slot
+                # KV blocks the step attends over (the new position's
+                # included) against the dense view of every table
+                bl = self.kv.block_len
+                live = int(((len_buf[:n] + bl) // bl).sum())
+                telemetry.bump("decode.kv_blocks_live", live)
+                telemetry.bump("decode.kv_blocks_dense", n * t)
+                stage.set(kv_blocks_live=live, kv_blocks_dense=n * t)
                 fn = self._decode_entry(b)
                 args = (jnp.asarray(tok_buf), jnp.asarray(tbl_buf),
                         jnp.asarray(len_buf), jnp.asarray(slot_buf))

@@ -60,6 +60,9 @@ def _zero() -> dict:
         # serving work
         "prefill_tokens": 0,
         "decode_tokens": 0,
+        # KV blocks the decode steps attend over, and their dense views
+        "decode.kv_blocks_live": 0,
+        "decode.kv_blocks_dense": 0,
         # paged-cache residency
         "blocks_reserved": 0,
         "blocks_freed": 0,

@@ -127,3 +127,45 @@ def test_smoke_scenario_shapes_are_the_compiled_ones():
         widths.add(cols.shape[1])
     assert lens == set(SMOKE_T)
     assert widths == {SMOKE_W}
+
+
+def test_paged_decode_entry_compiles_at_backlog_shapes(one_chip,
+                                                      no_persistent_cache,
+                                                      monkeypatch):
+    """The served decode entry at the backlog cell's shapes (qwen1.5-0.5b
+    widths, float32, 16 requests, 2,049 blocks of 16, max_len 2,048)
+    compiles for the chip with the paged kernel in it, and needs less
+    scratch than the 0.58e9 B the gathered dense view took."""
+    import json
+    from functools import partial
+
+    from repro.kernels import ops
+    from repro.models import init_model
+    from repro.models.paged import init_paged_pools, paged_decode
+    from repro.models.transformer import ModelConfig
+
+    cell = json.loads((ROOT / "bench" / "configs" / "qwen1.5-0.5b.json")
+                      .read_text())
+    cfg = ModelConfig(name=cell["name"], **cell["model"])
+    sv = cell["service"]
+    b, bl = sv["max_batch"], sv["block_len"]
+    t = sv["max_len"] // bl
+    n_blocks = b * t + 1
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(sds, jax.eval_shape(
+        partial(init_model, cfg=cfg), jax.random.PRNGKey(0)))
+    pools = jax.tree.map(sds, jax.eval_shape(
+        lambda: init_paged_pools(cfg, b, n_blocks, bl, jnp.float32)))
+    i32 = partial(jax.ShapeDtypeStruct, dtype=jnp.int32, sharding=one_chip)
+    # this process's backend is the CPU: lower the kernel for the chip
+    monkeypatch.setattr(ops, "use_interpret", lambda: False)
+    fn = jax.jit(partial(paged_decode, cfg=cfg, block_len=bl))
+    with jax.default_matmul_precision(cell["matmul_precision"]):
+        compiled = fn.lower(params, tokens=i32((b,)), pools=pools,
+                            tables=i32((b, t)), lens=i32((b,)),
+                            slots=i32((b,))).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= cfg.n_layers
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.58e9

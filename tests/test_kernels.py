@@ -50,6 +50,45 @@ def test_decode_attention(b, hq, hkv, s, d, dtype, tol):
                                atol=tol, rtol=tol)
 
 
+@pytest.mark.parametrize("block_len", [8, 16])
+@pytest.mark.parametrize("rep", [1, 2])
+def test_paged_decode_attention_matches_gathered_xla(block_len, rep):
+    """The paged kernel, reading the pool in place through the block
+    tables, equals ``_xla_decode`` over the gathered dense view of the same
+    pool: lengths of one position, a block less one, one block, whole
+    blocks, a partial second step and the table's last position; tables
+    padded with the null block; a padding lane (null table, one position)."""
+    from types import SimpleNamespace
+
+    from repro.models.attention import _xla_decode
+    from repro.models.paged import NULL_BLOCK, gather_paged_cache
+
+    hkv, d, max_len = 2, 16, 256
+    hq, t = hkv * rep, max_len // block_len
+    lens = np.array([1, block_len - 1, block_len, 2 * block_len,
+                     9 * block_len + 3, max_len - 1, 1], np.int32)
+    n = lens.size
+    live = -(-lens // block_len)
+    live[-1] = 0                                   # the padding lane
+    tables = np.full((n, t), NULL_BLOCK, np.int32)
+    ids = iter(RNG.permutation(np.arange(1, live.sum() + 1)))
+    for j in range(n):
+        tables[j, :live[j]] = [next(ids) for _ in range(live[j])]
+    shape = (int(live.sum()) + 1, block_len, hkv * d)
+    k_pool, v_pool = _rand(shape, jnp.float32), _rand(shape, jnp.float32)
+    q = _rand((n, hq, d), jnp.float32)
+    out = ops.paged_decode_attention(q, k_pool, v_pool, jnp.asarray(tables),
+                                     jnp.asarray(lens))
+    cfg = SimpleNamespace(n_kv_heads=hkv, head_dim=d)
+    [view] = gather_paged_cache([{"k": k_pool, "v": v_pool}],
+                                jnp.asarray(tables), jnp.asarray(lens),
+                                jnp.zeros((n,), jnp.int32), cfg)
+    with jax.default_matmul_precision("highest"):
+        expect = _xla_decode(q, view["k"], view["v"], view["len"])
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
+                               rtol=1e-5, atol=1e-5)
+
+
 @pytest.mark.parametrize("b,l,h,p,n,chunk", [
     (1, 96, 2, 16, 8, 32),
     (2, 70, 3, 8, 16, 32),   # ragged length vs chunk

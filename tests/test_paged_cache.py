@@ -3,6 +3,8 @@ gather-vs-dense bitwise equality, admission at exhaustion, buffer pooling.
 
 Property tests run under the offline hypothesis shim (keyword scalar
 strategies; sequences are derived from drawn seeds)."""
+from types import SimpleNamespace
+
 import numpy as np
 import pytest
 from hypothesis import given, settings
@@ -113,7 +115,8 @@ def test_gather_matches_dense_slicing_bitwise(seed, block_len, t):
     tables = rng.integers(0, num_blocks, size=(n, t)).astype(np.int32)
     lens = rng.integers(0, t * block_len + 1, size=(n,)).astype(np.int32)
     slots = np.zeros((n,), np.int32)
-    [out] = gather_paged_cache([{"k": pool}], tables, lens, slots)
+    cfg = SimpleNamespace(n_kv_heads=heads, head_dim=dim)
+    [out] = gather_paged_cache([{"k": pool}], tables, lens, slots, cfg)
     dense = np.stack([
         np.concatenate([pool[b] for b in tables[j]], axis=0)
         for j in range(n)])
@@ -157,3 +160,126 @@ def test_paged_kv_cache_validates_and_binds():
     assert (kv.tables_np[0] == 0).all()
     assert kv.allocator.blocks_free == kv.allocator.capacity
     assert kv.resident_bytes() > 0
+
+
+def _decode_by_gathering(params, cfg, tokens, pools, tables, lens, slots,
+                         block_len):
+    """The decode with every layer over the gathered dense view: the stock
+    ``decode_step``, then the one written position scattered back."""
+    import jax.numpy as jnp
+
+    from repro.models.paged import gather_paged_cache, is_slot_layer
+    from repro.models.transformer import decode_step
+
+    n = tokens.shape[0]
+    cache = gather_paged_cache(pools, tables, lens, slots, cfg)
+    logits, new_cache = decode_step(params, cfg, tokens, cache)
+    bidx = jnp.take_along_axis(tables, (lens // block_len)[:, None], 1)[:, 0]
+    off = lens % block_len
+    out = []
+    for layer, new in zip(pools, new_cache):
+        if is_slot_layer(layer):
+            out.append({k: v.at[slots].set(new[k]) for k, v in layer.items()})
+            continue
+        d = {}
+        for k, pool in layer.items():
+            idx = lens.reshape((n,) + (1,) * (new[k].ndim - 1))
+            upd = jnp.take_along_axis(new[k], idx, axis=1)[:, 0]
+            d[k] = pool.at[bidx, off].set(
+                upd.reshape((n,) + pool.shape[2:]).astype(pool.dtype))
+        out.append(d)
+    return jnp.argmax(logits, -1), out
+
+
+def _kernel_calls(jaxpr) -> int:
+    """Calls of the paged decode kernel's entry in a (nested) jaxpr."""
+    import jax
+
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.params.get("name") == "paged_decode_attention":
+            n += 1
+            continue
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    n += _kernel_calls(sub.jaxpr)
+                elif isinstance(sub, jax.extend.core.Jaxpr):
+                    n += _kernel_calls(sub)
+    return n
+
+
+# (arch, int8 KV, attention layers read in place by the kernel)
+ROUTES = [("qwen1.5-0.5b", False, 2), ("qwen2-1.5b", False, 2),
+          ("deepseek-v2-236b", False, 0), ("jamba-v0.1-52b", False, 1),
+          ("qwen1.5-0.5b", True, 0)]
+
+
+@pytest.mark.parametrize("arch,int8,in_place", ROUTES)
+def test_paged_decode_matches_gather_path(monkeypatch, arch, int8, in_place):
+    """Three greedy decode steps over stale, garbage-filled pools: float
+    MHA/GQA layers read the pool in place through the kernel, MLA latents,
+    int8 pools and recurrent state go through ``gather_paged_cache``. Each
+    step's tokens equal those of the all-gather decode from the same pools.
+    The new pools equal its pools bitwise up to the first layer read in
+    place (the write is the same scatter of the same values) and wherever
+    the step writes nothing; past that layer the written rows carry the
+    kernel's float32 rounding of the attention above them."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs import all_archs
+    from repro.models import init_model
+    from repro.models.paged import (NULL_BLOCK, init_paged_pools,
+                                    is_slot_layer, paged_decode,
+                                    reads_in_place)
+
+    if int8:
+        monkeypatch.setenv("REPRO_CACHE_QUANT", "1")
+    cfg = all_archs()[arch].reduced()
+    params = init_model(jax.random.PRNGKey(0), cfg)
+    max_batch, block_len, t = 3, 8, 4
+    num_blocks = max_batch * t + 1
+    rng = np.random.default_rng(7)
+    pools = jax.tree.map(
+        lambda a: jnp.asarray(
+            rng.integers(-127, 128, a.shape) if a.dtype == jnp.int8
+            else rng.normal(size=a.shape), a.dtype),
+        init_paged_pools(cfg, max_batch, num_blocks, block_len))
+    flags = [reads_in_place(p) for p in pools]
+    assert sum(flags) == in_place
+    exact_to = flags.index(True) if in_place else len(pools)
+    # two live requests and a padding lane (null table, scratch slot)
+    tables = np.full((3, t), NULL_BLOCK, np.int32)
+    tables[:2] = rng.permutation(np.arange(1, num_blocks))[:8].reshape(2, t)
+    lens = np.array([5, 2 * block_len - 2, 0], np.int32)
+    slots = np.array([0, 2, max_batch], np.int32)
+    tokens = jnp.asarray([3, 11, 0], jnp.int32)
+
+    kernel = jax.jit(lambda tok, p, ln: paged_decode(
+        params, cfg, tok, p, jnp.asarray(tables), ln, jnp.asarray(slots),
+        block_len))
+    gather = jax.jit(lambda tok, p, ln: _decode_by_gathering(
+        params, cfg, tok, p, jnp.asarray(tables), ln, jnp.asarray(slots),
+        block_len))
+    assert _kernel_calls(jax.make_jaxpr(kernel)(
+        tokens, pools, jnp.asarray(lens)).jaxpr) == in_place
+    for step in range(3):
+        ln = lens + step * np.array([1, 1, 0], np.int32)
+        got_tok, got = kernel(tokens, pools, jnp.asarray(ln))
+        tokens, pools = gather(tokens, pools, jnp.asarray(ln))
+        np.testing.assert_array_equal(np.asarray(got_tok), np.asarray(tokens))
+        bidx = tables[np.arange(3), ln // block_len]
+        for i, (a_layer, b_layer) in enumerate(zip(got, pools)):
+            for k in b_layer:
+                a, b = np.asarray(a_layer[k]), np.asarray(b_layer[k])
+                if i <= exact_to:
+                    np.testing.assert_array_equal(a, b)
+                    continue
+                written = np.zeros(b.shape[:2], bool)
+                if is_slot_layer(b_layer):
+                    written[slots] = True
+                else:
+                    written[bidx, ln % block_len] = True
+                np.testing.assert_array_equal(a[~written], b[~written])
+                np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
