@@ -2,7 +2,9 @@
 
 It finds a cell's configuration, traffic mix and per-layer metric readers
 by name (``BENCHMARK.json`` -> ``bench/configs/<config>.json``,
-``bench/traffic/<traffic>.json``, ``bench/metrics/<metric>.py``), refuses
+``bench/traffic/<traffic>.json``, ``bench/metrics/<metric>.py``, and a
+served configuration's ``family`` -> ``bench/reference/<family>.py``,
+``bench/family.py``), refuses
 to run without the accelerator, hands the cell to the driver its traffic
 names, and prints the result line.
 
@@ -70,6 +72,7 @@ class Cell:
     traffic: dict
     end_to_end: list
     per_layer: list
+    bench_dir: Path = BENCH_DIR
 
 
 def resolve_cell(bench: dict, name: str, bench_dir: Path = BENCH_DIR) -> Cell:
@@ -80,13 +83,16 @@ def resolve_cell(bench: dict, name: str, bench_dir: Path = BENCH_DIR) -> Cell:
     w = cells[name]
     config = _read_json(bench_dir / "configs" / f"{w['config']}.json")
     traffic = _read_json(bench_dir / "traffic" / f"{w['traffic']}.json")
+    if "family" in config and not (
+            bench_dir / "reference" / f"{config['family']}.py").exists():
+        raise Refused(f"no family bench/reference/{config['family']}.py")
     e2e = [m for m in bench["end_to_end"]
            if name in m.get("workloads", [name])]
     reported = {m["name"] for m in e2e}
     layer = [m for m in bench["per_layer"]
              if (name in m["workloads"] if "workloads" in m
                  else m["moves"] in reported)]
-    return Cell(name, w, config, traffic, e2e, layer)
+    return Cell(name, w, config, traffic, e2e, layer, bench_dir)
 
 
 def load_reader(metric: str, bench_dir: Path = BENCH_DIR):
@@ -191,11 +197,15 @@ class CompileCounter:
 
 class Tracer:
     """The profiler trace of a window, written inside the checkout and
-    removed once reduced."""
+    removed once reduced. ``opened`` and ``closed`` are the host clock's
+    times (``perf_counter``) at which the window's span opened and closed:
+    a call that starts and ends between them ran its device work inside
+    the traced window."""
 
     def __init__(self, workload: str):
         self.dir = OUT_DIR / "trace" / workload
         self._ann = None
+        self.opened = self.closed = None
 
     def start(self):
         """Start the profiler; it takes a moment, so before the window."""
@@ -210,11 +220,13 @@ class Tracer:
 
         self._ann = jax.profiler.TraceAnnotation(WINDOW_SPAN)
         self._ann.__enter__()
+        self.opened = time.perf_counter()
 
     def stop(self):
         import jax
 
         if self._ann is not None:
+            self.closed = time.perf_counter()
             self._ann.__exit__(None, None, None)
         jax.profiler.stop_trace()
 

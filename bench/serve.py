@@ -1,6 +1,7 @@
 """Driver of the served-path cells.
 
-Set-up makes the weights from the seed, builds ``AsyncLLMService`` over the
+Set-up makes the weights from the seed (the configuration's family,
+``bench/family.py``), builds ``AsyncLLMService`` over the
 paged KV pool with the cell's configuration, and compiles every prefill
 chunk bucket and every decode batch bucket the traffic can reach. Then one
 ``serve`` call runs the cell's mix (``bench/traffic.py``): the first
@@ -12,20 +13,27 @@ the run.
 
 The harness's spans sit around the service's prefill-chunk and decode
 calls, each of which ends on host-visible tokens. After the window, once
-the KV pools are freed, the plain reference (``bench/reference/qwen.py``)
-runs over a seeded sample of the requests served, the longest among them,
-and the widest gap by which a served token's logit lies below the
-reference's best is compared with its limit.
+the KV pools are freed, the family's plain reference (for a dense decoder
+``bench/reference/qwen.py``) runs over a seeded sample of the requests
+served, the longest among them, and the widest gap by which a served
+token's logit lies below the reference's best is compared with its limit.
+
+A traced run also keeps, for the per-layer readers, the live context of
+every decode step that ran inside the traced window and the ``op_name``
+path of every instruction of the decode entries (their compiled HLO, read
+after the window), so that a reader can take one kernel's device time
+from the trace and its least work from the steps.
 """
 from __future__ import annotations
 
 import asyncio
 import gc
 import time
+from functools import partial
 
 import numpy as np
 
-from bench import harness, traffic, work
+from bench import family, harness, trace, traffic, work
 
 TRACE_LEAD_S = 3.0      # the profiler starts this long before the window
 
@@ -91,6 +99,31 @@ def buckets(n: int) -> list:
         b *= 2
 
 
+def decode_args(svc, b: int) -> tuple:
+    """Arguments of the decode entry of batch bucket ``b``: every lane a
+    padding lane."""
+    import jax.numpy as jnp
+
+    kv = svc.kv
+    return (svc.params, jnp.zeros((b,), jnp.int32), kv.pools,
+            jnp.zeros((b, kv.blocks_per_seq), jnp.int32),
+            jnp.zeros((b,), jnp.int32),
+            jnp.full((b,), kv.scratch_slot, jnp.int32))
+
+
+def decode_op_names(svc, batch_buckets) -> dict:
+    """{module: {instruction: op_name path}} of the decode entries of these
+    batch buckets (those ``warm_entries`` compiled), read from their
+    compiled HLO (the persistent cache holds each program; run in the
+    serve's matmul precision, as they were)."""
+    out = {}
+    for b in sorted(batch_buckets):
+        compiled = svc._decode_entry(b).lower(*decode_args(svc, b)).compile()
+        module, names = trace.hlo_op_names(compiled.as_text())
+        out[module] = names
+    return out
+
+
 def warm_entries(svc, chunk: int, max_batch: int) -> int:
     """Compile (or load) every prefill chunk bucket and decode batch bucket
     the cell can reach; returns how many."""
@@ -109,27 +142,25 @@ def warm_entries(svc, chunk: int, max_batch: int) -> int:
         jax.block_until_ready(tok)
         n += 1
     for b in buckets(max_batch):
-        fn = svc._decode_entry(b)
-        toks = fn(svc.params, jnp.zeros((b,), jnp.int32), kv.pools,
-                  jnp.zeros((b, kv.blocks_per_seq), jnp.int32),
-                  jnp.zeros((b,), jnp.int32),
-                  jnp.full((b,), kv.scratch_slot, jnp.int32))[0]
+        toks = svc._decode_entry(b)(*decode_args(svc, b))[0]
         jax.block_until_ready(toks)
         n += 1
     return n
 
 
 class ServeProbe:
-    """Wraps the service's prefill-chunk and decode calls: spans, tokens and
-    FLOPs with their end times, the programs lowered by the window's edges,
-    and the traced part of the window."""
+    """Wraps the service's prefill-chunk and decode calls: spans, tokens,
+    FLOPs (``token_flops(ctx)`` of one token attending ``ctx`` positions)
+    and live context with their end times, the programs lowered by the
+    window's edges, and the traced part of the window."""
 
-    def __init__(self, svc, spans, model: dict, tracer=None, alter=None,
+    def __init__(self, svc, spans, token_flops, tracer=None, alter=None,
                  compiles=None):
-        self.svc, self.spans, self.model = svc, spans, model
+        self.svc, self.spans, self.token_flops = svc, spans, token_flops
         self.tracer, self.alter, self.compiles = tracer, alter, compiles
         self.prefills: list = []     # (t0, t1, n, start, completed)
-        self.decodes: list = []      # (t0, t1, n, flops)
+        # (t0, t1, n, flops, positions attended: sum of len + 1 per lane)
+        self.decodes: list = []
         self.lowered = [None, None]  # programs lowered by window open, close
         self.trace_from = self.trace_to = self.window_to = None
         self._tracing = "off"        # off -> on -> window -> done
@@ -190,22 +221,29 @@ class ServeProbe:
         if self.alter is not None:
             for r in batch:
                 r.generated[-1] = self.alter(r, r.generated[-1])
-        flops = sum(work.lm_token_flops(self.model, ln + 1) for ln in lens)
-        self.decodes.append((t0, t1, len(batch), flops))
+        flops = sum(self.token_flops(ln + 1) for ln in lens)
+        self.decodes.append((t0, t1, len(batch), flops,
+                             sum(ln + 1 for ln in lens)))
         self._tick(t1)
 
 
-def reference_gaps(cfg, params, samples, control: bool = False):
+def traced_decodes(decodes, tracer) -> list:
+    """The decode calls that started and ended inside the traced window:
+    their device work lies inside it."""
+    return [d for d in decodes
+            if d[0] >= tracer.opened and d[1] <= tracer.closed]
+
+
+def reference_gaps(fam, cfg, params, samples, control: bool = False):
     """The widest gap by which a served token's logit lies below the best of
-    the float32 reference (matrix products at ``highest``), over the sampled
-    requests; with ``control`` also the widest gap of the tokens that the
-    same reference computed in bfloat16 puts first at those positions.
-    Returns (gap, control gap or None, served tokens compared)."""
+    the family's float32 reference (matrix products at ``highest``), over
+    the sampled requests; with ``control`` also the widest gap of the
+    tokens that the same reference computed in bfloat16 puts first at
+    those positions. Returns (gap, control gap or None, served tokens
+    compared)."""
     import jax.numpy as jnp
 
-    from bench.reference import qwen
-
-    kw = qwen.kwargs(cfg["model"], cfg["reference"]["rms_norm_eps"])
+    kw = fam.kwargs(cfg)
     s_len = cfg["service"]["max_len"]
     worst = {"program": 0.0, "control": 0.0}
     n_tok = 0
@@ -215,14 +253,14 @@ def reference_gaps(cfg, params, samples, control: bool = False):
         toks[:len(seq)] = seq
         toks = jnp.asarray(toks)
         lo, n = len(prompt) - 1, len(generated)
-        ref = qwen.logits(params, toks, **kw)
+        ref = fam.logits(params, toks, **kw)
         chosen = {"program": np.zeros(s_len, np.int32)}
         chosen["program"][lo:lo + n] = generated
         if control:
-            low = qwen.logits(params, toks, **kw, dtype=jnp.bfloat16)
+            low = fam.logits(params, toks, **kw, dtype=jnp.bfloat16)
             chosen["control"] = np.asarray(jnp.argmax(low, axis=1), np.int32)
         for side, pick in chosen.items():
-            gaps = np.asarray(qwen.token_gaps(ref, jnp.asarray(pick)))
+            gaps = np.asarray(fam.token_gaps(ref, jnp.asarray(pick)))
             worst[side] = max(worst[side], float(gaps[lo:lo + n].max()))
         n_tok += n
     return worst["program"], worst["control"] if control else None, n_tok
@@ -268,7 +306,9 @@ def _serve(ctx, cfg, params, mix, seconds, spans, tracer, alter, compiles,
     svc = build_service(cfg, params, len(mix), clock)
     n_prog = warm_entries(svc, tr["chunk"], cfg["service"]["max_batch"])
     harness.log(f"{n_prog} entry points warmed")
-    probe = ServeProbe(svc, spans, cfg["model"], tracer, alter, compiles)
+    fam = family.load(ctx.cell)
+    probe = ServeProbe(svc, spans, partial(fam.token_flops, cfg["model"]),
+                       tracer, alter, compiles)
     reqs = [ServeRequest(i, prompt, new, arrived_iter=i)
             for i, (_, prompt, new) in enumerate(mix)]
     sched = SCHEDULERS["chunked_prefill"](chunk=tr["chunk"])
@@ -295,10 +335,9 @@ def run(ctx: harness.RunContext, alter=None, control: bool = False
     reference computed in bfloat16 puts first (``record["control"]``)."""
     import jax
 
-    from bench.weights import make_params
-
     cfg, tr = ctx.cell.config, ctx.cell.traffic
-    params = make_params(cfg["model"], harness.seed_child(ctx.seed, 1))
+    fam = family.load(ctx.cell)
+    params = fam.make_params(cfg["model"], harness.seed_child(ctx.seed, 1))
     jax.block_until_ready(params)
     mix = traffic.serve_requests(tr, harness.seed_child(ctx.seed, 2),
                                  ctx.seconds, cfg["model"]["vocab"])
@@ -308,6 +347,12 @@ def run(ctx: harness.RunContext, alter=None, control: bool = False
     svc, _, reqs, probe, _, t_open, t_close = serve_once(
         ctx, cfg, params, mix, ctx.seconds, spans, tracer, alter, compiles)
     mem = harness.memory_peak_bytes(harness.local_devices())
+    traced = traced_decodes(probe.decodes, tracer) \
+        if tracer is not None and tracer.closed is not None else None
+    if traced is not None:        # after the peak: it loads programs
+        with jax.default_matmul_precision(cfg["matmul_precision"]):
+            op_names = decode_op_names(
+                svc, buckets(cfg["service"]["max_batch"]))
     alloc = svc.kv.allocator
     counters = {"blocks_peak_used": alloc.peak_used,
                 "blocks_capacity": alloc.capacity}
@@ -322,17 +367,22 @@ def run(ctx: harness.RunContext, alter=None, control: bool = False
     prefills = [p for p in probe.prefills if t_open <= p[1] < t_end]
     decodes = [d for d in probe.decodes if t_open <= d[1] < t_end]
     out_tok = sum(1 for p in prefills if p[4]) + sum(d[2] for d in decodes)
-    flops = sum(work.lm_span_flops(cfg["model"], p[3], p[2])
+    flops = sum(fam.span_flops(cfg["model"], p[3], p[2])
                 for p in prefills) + sum(d[3] for d in decodes)
+    calls = sorted(prefills + decodes)
+    stall = max((b[0] - a[1] for a, b in zip(calls, calls[1:])), default=0.0)
     notes = [f"window: {len(prefills)} prefill chunks, {len(decodes)} decode "
-             f"steps, {out_tok} output tokens; programs lowered by its open "
-             f"and close {probe.lowered}; persistent cache {compiles.cache}; "
-             f"KV blocks {counters}"]
+             f"steps, {out_tok} output tokens; longest decode call "
+             f"{max((d[1] - d[0] for d in decodes), default=0.0)!r} s, "
+             f"longest time between calls {stall!r} s; programs lowered by "
+             f"its open and close {probe.lowered}; persistent cache "
+             f"{compiles.cache}; KV blocks {counters}"]
 
     sample = pick_samples(reqs, tr["check_requests"],
                           harness.seed_child(ctx.seed, 3))
     picked = [(reqs[i].prompt, reqs[i].generated) for i in sample]
-    gap, gap_control, n_tok = reference_gaps(cfg, params, picked, control)
+    gap, gap_control, n_tok = reference_gaps(fam, cfg, params, picked,
+                                             control)
     if not sample:
         gap = np.inf
     notes.append(f"reference over {len(sample)} requests, {n_tok} served "
@@ -343,7 +393,12 @@ def run(ctx: harness.RunContext, alter=None, control: bool = False
               "prefills": [p for p in probe.prefills if p[1] < t_end],
               "decodes": decodes, "counters": counters, "flops": flops,
               "peaks": work.peaks(harness.device_kind()),
-              "spans": spans.items}
+              "spans": spans.items, "model": cfg["model"],
+              "kv_itemsize": np.dtype(cfg["dtype"]).itemsize}
+    if traced is not None:
+        record.update(traced_decodes=traced, op_names=op_names)
+        notes.append(f"traced: {len(traced)} decode steps, "
+                     f"{sum(d[4] for d in traced)} positions attended")
     if control:
         record["control"] = gap_control
     return harness.RunOutput(

@@ -90,12 +90,13 @@ def main(argv=None) -> int:
     cell = harness.resolve_cell(harness.load_benchmark(), args.workload)
     harness.setup_process(cell)
 
-    from bench.weights import make_params
+    from bench import family
 
     cfg, tr = cell.config, cell.traffic
     ctx = harness.RunContext(cell, args.seed, args.seconds, False,
                              time.perf_counter())
-    params = make_params(cfg["model"], harness.seed_child(args.seed, 1))
+    params = family.load(cell).make_params(cfg["model"],
+                                           harness.seed_child(args.seed, 1))
     vocab = cfg["model"]["vocab"]
 
     base = traffic.serve_requests(tr, args.seed, args.seconds, vocab,

@@ -78,3 +78,23 @@ def lm_span_flops(cfg: dict, start: int, n: int) -> float:
     ctx_sum = n * start + n * (n + 1) // 2
     return 2.0 * mats * n + 4.0 * cfg["n_layers"] * cfg["n_heads"] * \
         cfg["head_dim"] * ctx_sum
+
+
+def paged_attention_work(cfg: dict, steps, itemsize: int) -> tuple:
+    """(operations, bytes) that decode attention read in place from a paged
+    KV pool needs at the least over ``steps`` [(requests, positions)], a
+    step's positions being the sum over its requests of the positions each
+    attends (its length + 1). Per layer and position: its K and V rows
+    (``n_kv_heads * head_dim`` elements of ``itemsize`` bytes each) read
+    once, and QK^T and PV, 4 * ``n_heads * head_dim`` operations; per layer
+    and request: q read and o written (``n_heads * head_dim`` elements
+    each). Every layer counts, as in a dense decoder whose layers all read
+    in place; the block tables and lengths, and the products a kernel does
+    beyond these (such as a block-diagonal query's), do not."""
+    h, kvh, hd = cfg["n_heads"], cfg["n_kv_heads"], cfg["head_dim"]
+    layers = cfg["n_layers"]
+    pos = sum(p for _, p in steps)
+    reqs = sum(n for n, _ in steps)
+    ops = 4.0 * layers * h * hd * pos
+    nbytes = float(layers * itemsize * (2 * kvh * hd * pos + 2 * h * hd * reqs))
+    return ops, nbytes

@@ -149,5 +149,8 @@ def test_program_spans_leave_the_harness_reduction_as_it_was(tmp_path):
                            device_lines=("tf_XLA",))
     assert set(s.span_s) == {"bench.eval", "bench.host"}
     assert s.span_count == {"bench.eval": 3, "bench.host": 3}
-    assert {n for n, _ in s.idle_gaps} <= {"bench.eval", "bench.host",
-                                           "host"}
+    # idle gaps are named by the innermost bench.* or repro.* span
+    names = {n for n, _ in s.idle_gaps}
+    assert names <= {"bench.eval", "bench.host", "repro.eval",
+                     "repro.ga.step", "host"}
+    assert "repro.ga.step" in names

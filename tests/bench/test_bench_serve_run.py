@@ -46,6 +46,9 @@ def test_serve_window_end_to_end(cpu_bench, traced):
     import jax
 
     cell = tiny_cell()
+    # as deep a backlog as the cell's: 16 tiny requests can all finish
+    # within the warm-up on a fast CPU, leaving the window empty
+    cell.traffic.update(n_requests=64)
     line = harness.run_cell(ctx(cell, traced), jax.devices())
     json.dumps(line)
     keys = {"correct", "attempted", "failed", "metrics", "device", "checks"}
